@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from benchmarks import hlo_cost
 from repro.configs.base import get_config
 from repro.core.plan import ExecutionPlan
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.optim import grad_compress
 
@@ -45,7 +46,7 @@ def _collect(txt):
 
 def bench(csv, sp=False):
     assert len(jax.devices()) >= TP, "run via benchmarks.run (forces devices)"
-    mesh = jax.make_mesh((TP,), ("model",))
+    mesh = make_mesh((TP,), ("model",))
     cfg0 = get_config("llama3.2-3b").reduced().replace(
         n_layers=N_LAYERS, n_heads=8, n_kv_heads=8)
     B, S = 2, 32

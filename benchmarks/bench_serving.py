@@ -83,6 +83,7 @@ import numpy as np
 
 from repro.configs.base import get_config
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.obs.trace import NULL_TRACER, Tracer, validate_chrome_trace
 from repro.serve.decode import ContinuousBatcher, Request
@@ -295,7 +296,7 @@ def _dual_structural_gate():
     mesh: dual-branch decode ticks must lower to the SAME collective counts
     as sequential ones (ONE fused all-reduce).  Returns the fal counts."""
     from repro.core import tp
-    mesh = jax.make_mesh((2,), ("model",))
+    mesh = make_mesh((2,), ("model",))
     return tp.assert_dual_no_extra_collectives(mesh, modes=("fal",))["fal"]
 
 

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import get_config
 from repro.core import tp
 from repro.core.plan import ExecutionPlan
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.train import trainer
 
@@ -37,7 +38,7 @@ state, hist = trainer.train(cfg, steps=30, batch=8, seq_len=64, plan=plan,
 # explicit partial-sum shard_map stack (model.decoder_stack_tp) under an
 # ExecutionPlan.from_mesh(mesh, tp="explicit") — the HLO below is the
 # production collective structure, not a toy's
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 for mode in ("preln", "fal"):
     init, fwd = tp.make_tp_forward(mesh, n_layers=4, d=64, d_ff=256,
                                    n_heads=8, mode=mode)

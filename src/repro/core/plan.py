@@ -44,6 +44,12 @@ Plan axes:
   ``compressed_psum_scatter``), cutting measured gradient payload bytes
   ~4x for int8 (``bench_comm --json`` → ``grad_payload_bytes``).  Lossy
   by design, like the Fig 7 baselines; 'none' lowers byte-identical HLO.
+* ``kernels`` — auto | pallas | interpret | ref.  Which implementation
+  serves the paged-attention and page-copy dispatchers
+  (``kernels/ops.py``): ``auto`` runs the compiled Pallas kernels on a TPU
+  and the jnp oracles elsewhere; the other values pin one — the compiled
+  kernel, the same kernel under the Pallas interpreter (CPU tests), or the
+  gather-based oracle (the reference a chip run is checked against).
 
 Inside the explicit-TP shard_map the blocks see ``plan.inner()`` — the same
 plan with ``mesh=None`` and ``local_tp_size`` set; ``plan.tp_axis`` is then
@@ -100,6 +106,9 @@ class TPStyle(enum.Enum):
                 f"{[t.value for t in cls]}") from None
 
 
+#: ``ExecutionPlan.kernels`` values (``kernels.ops.kernel_args`` maps them)
+KERNEL_PATHS = ("auto", "pallas", "interpret", "ref")
+
 #: families with an explicit partial-sum TP stack (decoder_stack_tp)
 EXPLICIT_TP_FAMILIES = ("dense", "moe", "vlm")
 
@@ -123,6 +132,7 @@ class ExecutionPlan:
     sequence_parallel: bool = False
     dual_branch: bool = False
     grad_compress: str = "none"            # none | int8 | lowrank
+    kernels: str = "auto"                  # auto | pallas | interpret | ref
     mesh: Any = None                       # jax.sharding.Mesh | None
     data_axes: Tuple[str, ...] = ()
     model_axis: str = "model"
@@ -183,6 +193,10 @@ class ExecutionPlan:
         """Same plan with compressed backward TP collectives selected."""
         return dataclasses.replace(self, grad_compress=str(method))
 
+    def with_kernels(self, kernels: str) -> "ExecutionPlan":
+        """Same plan with the kernel implementation pinned."""
+        return dataclasses.replace(self, kernels=str(kernels))
+
     def inner(self) -> "ExecutionPlan":
         """The plan a shard_map local body sees: no mesh (collectives are
         explicit inside), ``local_tp_size`` pinned to the model-axis size."""
@@ -240,6 +254,9 @@ class ExecutionPlan:
                 f"single tokens against KV caches")
         if self.dual_branch:
             self._validate_dual_branch(cfg)
+        if self.kernels not in KERNEL_PATHS:
+            raise ValueError(f"unknown kernels {self.kernels!r}; valid: "
+                             f"{'/'.join(KERNEL_PATHS)}")
         if self.grad_compress not in ("none", "int8", "lowrank"):
             raise ValueError(
                 f"unknown grad_compress {self.grad_compress!r}; valid: "

@@ -105,7 +105,6 @@ def make_tp_decode_step(mesh, d, d_ff, n_heads, mode, axis="model", *,
     n_valid (B,), a1_sig (B,1,d)) -> (x_out, new_cache)``.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import shard_map
     from repro.core.plan import ExecutionPlan, Phase
     from repro.launch import mesh as MX
     from repro.models import attention as A
@@ -135,10 +134,10 @@ def make_tp_decode_step(mesh, d, d_ff, n_heads, mode, axis="model", *,
                 block_tables=bt, n_valid=n_valid)
             return out, new_cache["k"], new_cache["v"]
 
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(wspecs, P(), kv, kv, P(), P(), P(), P()),
-                       out_specs=(P(), kv, kv),
-                       check_vma=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(wspecs, P(), kv, kv, P(), P(), P(), P()),
+                           out_specs=(P(), kv, kv),
+                           check_vma=False)
         out, ck, cv = fn(params, x, cache["k"], cache["v"], bt, pos,
                          n_valid, a1_sig)
         return out, {"k": ck, "v": cv}

@@ -16,10 +16,12 @@ of wo); step (h, t) accumulates tile ``h*T + t``'s contribution to the FFN
 output row in fp32 VMEM scratch.  Emission: attention out at the last page
 step of each (b, h); FFN out at the last (h, t) step of each b.
 
-Requires F % (Hkv * T) == 0 (the ``kernels.ops.dual_branch_decode``
-dispatcher falls back to separate attention + FFN calls otherwise — still
-dependency-free, just not fused).  Tile width F/(Hkv*T) is ideally a
-multiple of 128 (lane width); smaller tiles are compiler-padded.
+Requires F % (Hkv * T) == 0; the TPU compiler further needs the tile
+width F/(Hkv*T) to be a multiple of 128 (lane width), so the
+``kernels.ops.dual_branch_decode`` dispatcher selects this kernel only then
+and issues separate attention + FFN calls otherwise (still
+dependency-free, just not fused).  The FFN row operand and output are
+(B, 1, Dm) so each (1, 1, Dm) row block spans the full last two dims.
 
 Target: TPU.  Validated with ``interpret=True`` on CPU against
 ``ref.paged_attention_ref`` + ``layers.mlp_apply`` in ``tests/test_dual_branch.py``.
@@ -62,7 +64,7 @@ def _dual_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, x_ref, *ffn_refs,
         ffn_scr[...] = jnp.zeros_like(ffn_scr)
 
     # ---- FFN branch: one hidden-dim tile per grid step (MXU) -------------
-    xr = x_ref[...].astype(jnp.float32)                   # (1, Dm)
+    xr = x_ref[0].astype(jnp.float32)                     # (1, Dm)
     hi = jax.lax.dot_general(
         xr, wi_ref[...].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)               # (1, Ft)
@@ -116,16 +118,16 @@ def _dual_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, x_ref, *ffn_refs,
 
     @pl.when((ih == nh - 1) & (it == nt - 1))
     def _emit_ffn():
-        f_ref[...] = ffn_scr[...].astype(f_ref.dtype)
+        f_ref[0] = ffn_scr[...].astype(f_ref.dtype)
 
 
 def fused_dual_branch_decode(q, k_pages, v_pages, block_tables, seq_lens,
                              x, ffn, *, kind="swiglu", scale=None,
                              interpret=False):
     """q: (B, H, D); k_pages/v_pages: (P, page, Hkv, D*); block_tables:
-    (B, T) int32; seq_lens: (B,) int32; x: (B, Dm) FFN input rows; ffn:
+    (B, T) int32; seq_lens: (B,) int32; x: (B, 1, Dm) FFN input rows; ffn:
     {"wi" (Dm, F) [, "wg" (Dm, F)], "wo" (F, Dm)}.
-    Returns (attn (B, H, Dv), ffn_out (B, Dm))."""
+    Returns (attn (B, H, Dv), ffn_out (B, 1, Dm))."""
     B, H, D = q.shape
     page, Hkv = k_pages.shape[1], k_pages.shape[2]
     Dv = v_pages.shape[-1]
@@ -153,7 +155,7 @@ def fused_dual_branch_decode(q, k_pages, v_pages, block_tables, seq_lens,
                      lambda b, h, t, bt, sl: (bt[b, t], h, 0, 0)),
         pl.BlockSpec((1, 1, page, Dv),
                      lambda b, h, t, bt, sl: (bt[b, t], h, 0, 0)),
-        pl.BlockSpec((1, Dm), lambda b, h, t, bt, sl: (b, 0)),
+        pl.BlockSpec((1, 1, Dm), lambda b, h, t, bt, sl: (b, 0, 0)),
         pl.BlockSpec((Dm, Ft), lambda b, h, t, bt, sl: (0, h * T + t)),
     ]
     operands = [qg, kt, vt, x, ffn["wi"]]
@@ -171,7 +173,7 @@ def fused_dual_branch_decode(q, k_pages, v_pages, block_tables, seq_lens,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, G, Dv), lambda b, h, t, bt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((1, Dm), lambda b, h, t, bt, sl: (b, 0)),
+            pl.BlockSpec((1, 1, Dm), lambda b, h, t, bt, sl: (b, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((G,), jnp.float32),
@@ -186,7 +188,7 @@ def fused_dual_branch_decode(q, k_pages, v_pages, block_tables, seq_lens,
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
-                   jax.ShapeDtypeStruct((B, Dm), x.dtype)],
+                   jax.ShapeDtypeStruct((B, 1, Dm), x.dtype)],
         interpret=interpret,
     )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
     return out.reshape(B, H, Dv), ffn_out

@@ -1,23 +1,28 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On a CPU host (this container, and the dry-run) Pallas TPU kernels cannot
-lower, so ``use_pallas=False`` (default on CPU) dispatches to the jnp
-blockwise/fused implementations with identical numerics.  On TPU, pass
-``use_pallas=True`` (or set REPRO_USE_PALLAS=1) to run the kernels.
+Each dispatcher runs one of three implementations of the same contract:
 
-Every dispatcher records the path it lowered (``fused-tpu`` vs
-``cpu-fallback``) per call site into the default metrics registry
-(``repro.obs``) at trace time — a traced program's path cannot change
-without a re-trace, so ``dispatch_paths()`` is the ground truth the
-benchmark JSONs stamp as ``dispatch_path`` (a runtime measurement, not a
-bench-side guess).  ``kernel_dispatch_total`` therefore counts TRACES, not
-executed calls.
+* ``fused-tpu`` — the compiled Pallas kernel (``use_pallas=True``; the
+  default when JAX's default backend is a TPU);
+* ``interpret`` — the same kernel under the Pallas interpreter
+  (``interpret=True``; how the CPU tests run the kernels);
+* ``cpu-fallback`` — the jnp oracle in ``kernels/ref.py`` or the jnp
+  blockwise equivalent (``use_pallas=False``; the default elsewhere).
+
+Model code selects one through ``ExecutionPlan.kernels``, which
+``kernel_args`` turns into these keyword arguments.
+
+Every dispatcher records the path it lowered per call site into the
+default metrics registry (``repro.obs``) at trace time — a traced
+program's path cannot change without a re-trace, so ``dispatch_paths()``
+is the ground truth the benchmark JSONs stamp as ``dispatch_path`` (a
+runtime measurement, not a bench-side guess).  ``kernel_dispatch_total``
+therefore counts TRACES, not executed calls.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +33,20 @@ from repro.kernels import ref as _ref
 from repro.obs import metrics as _metrics
 
 FUSED = "fused-tpu"
+INTERPRET = "interpret"
 FALLBACK = "cpu-fallback"
+
+#: ``ExecutionPlan.kernels`` values -> dispatcher keyword arguments
+_KERNEL_ARGS = {
+    "auto": {},
+    "pallas": {"use_pallas": True},
+    "interpret": {"interpret": True},
+    "ref": {"use_pallas": False},
+}
+
+#: the fused dual-branch kernel tiles d_ff in lane-width (128) columns; a
+#: narrower tile is refused by the TPU compiler
+_LANE = 128
 
 #: last path traced per dispatcher call site (survives registry resets:
 #: a warmup reset must not un-measure an already-compiled program)
@@ -39,10 +57,22 @@ _DISPATCH_PATHS = {}
 _SITE_SUFFIX = ""
 
 
-def _record_dispatch(site: str, fused: bool) -> str:
+def kernel_args(kernels: str) -> dict:
+    """Dispatcher keyword arguments for an ``ExecutionPlan.kernels`` value
+    (``core.plan.KERNEL_PATHS``; ``ExecutionPlan.validate`` rejects
+    others)."""
+    return _KERNEL_ARGS[kernels]
+
+
+def _path(use_pallas: bool, interpret: bool) -> str:
+    if interpret:
+        return INTERPRET
+    return FUSED if use_pallas else FALLBACK
+
+
+def _record_dispatch(site: str, path: str) -> str:
     if _SITE_SUFFIX:
         site = f"{site}.{_SITE_SUFFIX}"
-    path = FUSED if fused else FALLBACK
     _DISPATCH_PATHS[site] = path
     _metrics.default_registry().counter(
         f"kernel_dispatch_total.{site}.{path}", unit="traces",
@@ -83,8 +113,8 @@ def _kv_variant(site: str, k_pages, k_scale) -> str:
 
 
 def dispatch_paths() -> dict:
-    """{call site: 'fused-tpu' | 'cpu-fallback'} for every dispatcher
-    traced so far in this process."""
+    """{call site: 'fused-tpu' | 'interpret' | 'cpu-fallback'} for every
+    dispatcher traced so far in this process."""
     return dict(_DISPATCH_PATHS)
 
 
@@ -95,8 +125,6 @@ def reset_dispatch_paths():
 
 
 def _default_use_pallas():
-    if os.environ.get("REPRO_USE_PALLAS"):
-        return True
     return jax.default_backend() == "tpu"
 
 
@@ -105,7 +133,7 @@ def _default_use_pallas():
 def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
                     use_pallas=None, interpret=False):
     use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
-    _record_dispatch("flash_attention", use_pallas or interpret)
+    _record_dispatch("flash_attention", _path(use_pallas, interpret))
     if use_pallas or interpret:
         return _fa.flash_attention(q, k, v, causal=causal, block_q=block_q,
                                    block_k=block_k, interpret=interpret)
@@ -118,13 +146,13 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
                            k_scale=None, v_scale=None, use_pallas=None,
                            interpret=False):
     """Paged-KV decode attention: q (B,H,D) against (P,page,Hkv,D*) pools
-    addressed through (B,T) block tables; optional (P,page) fp32
+    addressed through (B,T) block tables; optional (P,1,page) fp32
     ``k_scale``/``v_scale`` pools dequantize narrow-dtype pages at the
     VMEM load (fp32 softmax accumulate).  Pallas kernel on TPU;
     gather-based jnp oracle on CPU (identical numerics)."""
     use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
     _record_dispatch(_kv_variant("paged_decode_attention", k_pages, k_scale),
-                     use_pallas or interpret)
+                     _path(use_pallas, interpret))
     if use_pallas or interpret:
         from repro.kernels import paged_attention as _pa
         return _pa.paged_decode_attention(q, k_pages, v_pages, block_tables,
@@ -151,7 +179,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, pos, n_valid, *,
     CPU (identical numerics)."""
     use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
     _record_dispatch(_kv_variant("paged_chunk_attention", k_pages, k_scale),
-                     use_pallas or interpret)
+                     _path(use_pallas, interpret))
     if use_pallas or interpret:
         from repro.kernels import paged_attention as _pa
         return _pa.paged_chunk_attention(q, k_pages, v_pages, block_tables,
@@ -184,7 +212,7 @@ def paged_packed_attention(q, k_pages, v_pages, block_tables, tok_slot,
     numerics)."""
     use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
     _record_dispatch(_kv_variant("paged_packed_attention", k_pages, k_scale),
-                     use_pallas or interpret)
+                     _path(use_pallas, interpret))
     if use_pallas or interpret:
         from repro.kernels import paged_attention as _pa
         return _pa.paged_packed_attention(q, k_pages, v_pages, block_tables,
@@ -208,30 +236,35 @@ def dual_branch_decode(q, k_pages, v_pages, block_tables, seq_lens, mlp_in,
     dense-MLP params {"wi"[, "wg"], "wo"}.  Returns
     (attn (B, H, Dv), ffn_out (B, 1, Dm)).
 
-    On TPU (or interpret mode), when d_ff divides into Hkv*T tiles, both
-    branches run in ONE fused Pallas kernel that overlaps the block-table
-    page DMAs with the FFN matmuls (``kernels.dual_branch``); otherwise the
-    branches are issued as two independent ops (XLA overlaps them).  The
+    The fused Pallas kernel (``kernels.dual_branch``) runs both branches in
+    ONE dispatch that overlaps the block-table page DMAs with the FFN
+    matmuls.  It needs d_ff to split into Hkv*T column tiles of a multiple
+    of 128 lanes; otherwise the branches are issued as two independent ops
+    (XLA overlaps them), recorded under the site
+    ``dual_branch_decode.two_op`` so the change of path is visible.  The
     CPU path runs exactly the ops of the sequential decode path — the
     gather-based ref oracle plus ``layers.mlp_apply`` — so dual-branch
-    logits are bit-identical to sequential ones."""
+    logits match sequential ones."""
     use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
     from repro.models.layers import mlp_apply
+    path = _path(use_pallas, interpret)
     n_tiles = k_pages.shape[2] * block_tables.shape[1]
-    _record_dispatch("dual_branch_decode", use_pallas or interpret)
-    if (use_pallas or interpret) and ffn["wi"].shape[-1] % n_tiles == 0:
+    d_ff = ffn["wi"].shape[-1]
+    if path != FALLBACK and d_ff % (n_tiles * _LANE) == 0:
         from repro.kernels import dual_branch as _db
+        _record_dispatch("dual_branch_decode", path)
         attn, y = _db.fused_dual_branch_decode(
-            q, k_pages, v_pages, block_tables, seq_lens, mlp_in[:, 0], ffn,
+            q, k_pages, v_pages, block_tables, seq_lens, mlp_in, ffn,
             kind=kind, interpret=interpret)
-        return attn, y[:, None]
-    if use_pallas:
-        from repro.kernels import paged_attention as _pa
-        attn = _pa.paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                          seq_lens)
-    else:
+        return attn, y
+    _record_dispatch("dual_branch_decode.two_op", path)
+    if path == FALLBACK:
         attn = _ref.paged_attention_ref(q, k_pages, v_pages, block_tables,
                                         seq_lens)
+    else:
+        from repro.kernels import paged_attention as _pa
+        attn = _pa.paged_decode_attention(q, k_pages, v_pages, block_tables,
+                                          seq_lens, interpret=interpret)
     return attn, mlp_apply(ffn, mlp_in, kind)
 
 
@@ -244,7 +277,7 @@ def copy_pages(pool, src, dst, *, use_pallas=None, interpret=False):
     TPU (pool aliased into the output); scatter-based jnp oracle on CPU
     (identical bytes)."""
     use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
-    _record_dispatch("copy_pages", use_pallas or interpret)
+    _record_dispatch("copy_pages", _path(use_pallas, interpret))
     if use_pallas or interpret:
         from repro.kernels import paged_attention as _pa
         return _pa.page_copy(pool, src, dst, interpret=interpret)
@@ -256,7 +289,7 @@ def copy_pages(pool, src, dst, *, use_pallas=None, interpret=False):
 def fused_ln_add(x, a1n, scale, bias=None, *, kind="rmsnorm",
                  use_pallas=None, interpret=False):
     use_pallas = _default_use_pallas() if use_pallas is None else use_pallas
-    _record_dispatch("fused_ln_add", use_pallas or interpret)
+    _record_dispatch("fused_ln_add", _path(use_pallas, interpret))
     if use_pallas or interpret:
         return _fla.fused_ln_add(x, a1n, scale, bias, kind=kind,
                                  interpret=interpret)

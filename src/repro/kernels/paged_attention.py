@@ -19,13 +19,15 @@ one physical page into VMEM — no gathered (B, T*page) copy is ever
 materialised in HBM.
 
 Quantized KV pages: every paged kernel takes optional per-page-row
-``k_scale``/``v_scale`` pools ((P, page_size) fp32, shared across KV
-heads).  When present, the page's scale row rides the same block-table
-index map as its K/V page (one extra tiny DMA per page step) and the
-narrow-dtype page is dequantized at the existing ``.astype(f32)`` load —
-narrow in, fp32 softmax accumulate — so the online-softmax math is
-byte-identical to the unquantized path and only the storage rounding
-differs.  With scales absent the lowered program is unchanged.
+``k_scale``/``v_scale`` pools ((P, 1, page_size) fp32, shared across KV
+heads; the unit middle dim makes the (1, 1, page) block span the pool's
+full last two dims, as the TPU's (8, 128) tiling rule requires).  When
+present, the page's scale row rides the same block-table index map as its
+K/V page (one extra tiny DMA per page step) and is applied along the key
+axis, where it already lies in lanes: K's scale multiplies the (G, page)
+scores and V's scale the (G, page) softmax weights before the PV matmul.
+Narrow pages in, fp32 softmax accumulate; with scales absent the lowered
+program is unchanged.
 
 Grid: (B, Hkv, T) with T sequential (TPU grids execute in order); the G
 query heads sharing a kv head are processed together as a (G, D) tile —
@@ -72,11 +74,11 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
         k = k_ref[0, 0].astype(jnp.float32)               # (page, D)
-        if quantized:
-            k = k * ks_ref[0].astype(jnp.float32)[:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (G, page)
+        if quantized:
+            s = s * ks_ref[0]                             # (1, page) rows
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos < seq_len, s, NEG_INF)
 
@@ -87,10 +89,9 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
         m_scr[...] = m_cur
         v = v_ref[0, 0].astype(jnp.float32)               # (page, Dv)
-        if quantized:
-            v = v * vs_ref[0].astype(jnp.float32)[:, None]
+        pv = p * vs_ref[0] if quantized else p
         acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     # skip pages entirely past the request's history
@@ -108,7 +109,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
                            interpret=False):
     """q: (B, H, D); k_pages/v_pages: (P, page, Hkv, D*);
     block_tables: (B, T) int32; seq_lens: (B,) int32;
-    k_scale/v_scale: optional (P, page) fp32 dequant pools -> (B, H, Dv)."""
+    k_scale/v_scale: optional (P, 1, page) fp32 dequant pools
+    -> (B, H, Dv)."""
     B, H, D = q.shape
     page, Hkv = k_pages.shape[1], k_pages.shape[2]
     Dv = v_pages.shape[-1]
@@ -131,8 +133,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
     ]
     operands = [qg, kt, vt]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page),
-                                  lambda b, h, t, bt, sl: (bt[b, t], 0))] * 2
+        in_specs += [pl.BlockSpec((1, 1, page),
+                                  lambda b, h, t, bt, sl:
+                                  (bt[b, t], 0, 0))] * 2
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -181,11 +184,11 @@ def _paged_chunk_kernel(bt_ref, pos_ref, nv_ref, q_ref, k_ref, v_ref, *rest,
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)               # (C*G, D)
         k = k_ref[0, 0].astype(jnp.float32)               # (page, D)
-        if quantized:
-            k = k * ks_ref[0].astype(jnp.float32)[:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (C*G, page)
+        if quantized:
+            s = s * ks_ref[0]                             # (1, page) rows
         # row r is chunk lane c = r // G at logical position pos + c: causal
         # within the chunk (k_pos <= q_pos) over live keys (k_pos < seq_len)
         q_pos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // G
@@ -203,10 +206,9 @@ def _paged_chunk_kernel(bt_ref, pos_ref, nv_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
         m_scr[...] = m_cur
         v = v_ref[0, 0].astype(jnp.float32)               # (page, Dv)
-        if quantized:
-            v = v * vs_ref[0].astype(jnp.float32)[:, None]
+        pv = p * vs_ref[0] if quantized else p
         acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     # skip pages entirely past the lane's live history
@@ -260,9 +262,9 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, pos, n_valid, *,
     ]
     operands = [qg, kt, vt]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page),
+        in_specs += [pl.BlockSpec((1, 1, page),
                                   lambda b, h, t, bt, ps, nv:
-                                  (bt[b, t], 0))] * 2
+                                  (bt[b, t], 0, 0))] * 2
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -311,11 +313,11 @@ def _paged_packed_kernel(bt_ref, sl_ref, ps_ref, q_ref, k_ref, v_ref, *rest,
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
         k = k_ref[0, 0].astype(jnp.float32)               # (page, D)
-        if quantized:
-            k = k * ks_ref[0].astype(jnp.float32)[:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (G, page)
+        if quantized:
+            s = s * ks_ref[0]                             # (1, page) rows
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos <= q_pos, s, NEG_INF)
 
@@ -326,10 +328,9 @@ def _paged_packed_kernel(bt_ref, sl_ref, ps_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
         m_scr[...] = m_cur
         v = v_ref[0, 0].astype(jnp.float32)               # (page, Dv)
-        if quantized:
-            v = v * vs_ref[0].astype(jnp.float32)[:, None]
+        pv = p * vs_ref[0] if quantized else p
         acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     # skip pages entirely past this token's position; padding tokens
@@ -384,9 +385,9 @@ def paged_packed_attention(q, k_pages, v_pages, block_tables, tok_slot,
     ]
     operands = [qg, kt, vt]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page),
+        in_specs += [pl.BlockSpec((1, 1, page),
                                   lambda t, h, j, bt, sl, ps:
-                                  (bt[sl[t], j], 0))] * 2
+                                  (bt[sl[t], j], 0, 0))] * 2
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

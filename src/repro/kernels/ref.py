@@ -2,7 +2,7 @@
 validation sweeps).
 
 The paged oracles take optional per-page-row ``k_scale``/``v_scale`` pools
-((P, page_size) fp32, shared across KV heads — the quantized-KV page
+((P, 1, page_size) fp32, shared across KV heads — the quantized-KV page
 format): when present, gathered K/V rows are dequantized as
 ``row.astype(f32) * scale`` right where the unquantized path upcasts, so
 the fp32 softmax math downstream is IDENTICAL and the only difference is
@@ -15,12 +15,12 @@ import jax.numpy as jnp
 
 def _dequant(rows, scale_pages, block_tables):
     """rows: gathered K/V (..., Sk, Hkv, D) already fp32; scale_pages:
-    (P, page_size) fp32 per-row scales or None; block_tables matches the
-    gather that produced ``rows``.  Returns rows * scale (broadcast over
-    heads and head dim)."""
+    (P, 1, page_size) fp32 per-row scales or None; block_tables matches
+    the gather that produced ``rows``.  Returns rows * scale (broadcast
+    over heads and head dim)."""
     if scale_pages is None:
         return rows
-    s = scale_pages[block_tables]                 # (..., T, page)
+    s = scale_pages[:, 0][block_tables]           # (..., T, page)
     s = s.reshape(s.shape[:-2] + (-1,))           # (..., Sk)
     return rows * s[..., None, None].astype(jnp.float32)
 
@@ -50,7 +50,7 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, seq_lens, *,
     k_pages/v_pages: (P, page_size, Hkv, D*) pools;
     block_tables: (B, T) int32 logical-block -> physical-page;
     seq_lens: (B,) valid keys per request (gathered index < seq_len);
-    k_scale/v_scale: optional (P, page_size) fp32 dequant scale pools.
+    k_scale/v_scale: optional (P, 1, page_size) fp32 dequant scale pools.
     Returns (B, H, Dv).
     """
     B, H, D = q.shape

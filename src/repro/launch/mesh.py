@@ -1,4 +1,9 @@
-"""Production mesh + PartitionSpec trees.
+"""Meshes + PartitionSpec trees.
+
+``make_mesh`` is the one mesh constructor (Auto axes); ``make_host_mesh``
+lays a (data, model) mesh over the devices present (``launch/train.py
+--mesh``); the production shapes below exist only for the dry-run, which
+compiles them against described devices.
 
 Single pod: (16, 16) over ("data", "model") — 256 chips (v5e pod).
 Multi-pod:  (2, 16, 16) over ("pod", "data", "model") — 512 chips.  The
@@ -24,14 +29,35 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """The repo's one mesh constructor: ``jax.make_mesh`` with every axis
+    ``Auto``.  The sharded code relies on GSPMD propagation through
+    reshapes and gathers, which Explicit-typed axes (the JAX default) turn
+    into ``ShardingTypeError``s.  ``devices`` defaults to
+    ``jax.devices()``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
+def make_host_mesh(model: int):
+    """(data, model) mesh over every device of this host, ``model`` of them
+    along the tensor-parallel axis."""
+    n = len(jax.devices())
+    if model < 1 or n % model:
+        raise ValueError(f"model axis {model} does not divide the {n} "
+                         f"devices present")
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def data_axes_of(mesh) -> tuple:

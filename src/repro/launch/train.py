@@ -1,13 +1,14 @@
 """Training launcher.
 
-Local (this container, 1 CPU device):
+One device:
     PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b \
         --reduced --steps 100 --batch 8 --seq 128 --connection fal
 
-Production (TPU pod / forced host devices): add --mesh single|multi to run
-the real sharded train step (the same code path the dry-run lowers).
+Sharded: add ``--mesh MODEL`` to run the sharded train step over every
+device present, as a (data, model) mesh with MODEL devices on the model
+axis (``--mesh 4 --tp explicit`` on a four-chip host).  The production
+16x16 / 2x16x16 shapes are compiled, not run, by ``launch/dryrun.py``.
 """
-import os
 import argparse
 
 
@@ -24,7 +25,9 @@ def main():
     ap.add_argument("--schedule", default="cosine",
                     choices=["cosine", "onecycle", "wsd"])
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--mesh", default=None, choices=[None, "single", "multi"])
+    ap.add_argument("--mesh", type=int, default=None, metavar="MODEL",
+                    help="shard over every device present: a (data, model) "
+                         "mesh with MODEL devices on the model axis")
     ap.add_argument("--tp", default="gspmd", choices=["gspmd", "explicit"],
                     help="with --mesh: explicit = shard_map partial-sum TP "
                          "stack (the paper's per-block collective structure)")
@@ -37,32 +40,28 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    if args.mesh:
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                                   " --xla_force_host_platform_device_count=512")
-
-    import jax
     from repro.configs.base import get_config
     from repro.core.plan import ExecutionPlan
     from repro.launch import mesh as MX
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train import trainer
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     if args.connection:
         cfg = cfg.replace(connection=args.connection)
 
-    in_shardings = None
     if args.tp == "explicit" and not args.mesh:
         raise ValueError("--tp explicit requires --mesh (the explicit-TP "
-                         "stack shards over the production mesh)")
+                         "stack shards over the device mesh)")
     if args.sp and (args.tp != "explicit" or not args.mesh):
         raise ValueError("--sp requires --mesh and --tp explicit "
                          "(sequence-parallel LN regions live inside the "
                          "explicit partial-sum shard_map stack)")
     if args.mesh:
-        mesh = MX.make_production_mesh(multi_pod=(args.mesh == "multi"))
+        mesh = MX.make_host_mesh(args.mesh)
         plan = ExecutionPlan.from_mesh(mesh, tp=args.tp, sp=args.sp,
                                        model_axis=MX.MODEL)
     else:

@@ -160,7 +160,7 @@ def _gather_dequant(pages, scale_pages, block_tables):
     g = paged_gather(pages, block_tables)
     if scale_pages is None:
         return g
-    s = paged_gather(scale_pages, block_tables)                   # (B, Sk)
+    s = paged_gather(scale_pages[:, 0], block_tables)             # (B, Sk)
     return g.astype(jnp.float32) * s[..., None, None]
 
 
@@ -195,6 +195,12 @@ def chunk_attention(q, k, v, q_pos, *, window=0, cap=0.0, scale=None):
 KV_DTYPES = ("", "bf16", "int8", "fp8")
 _KV_STORAGE = {"bf16": "bfloat16", "int8": "int8", "fp8": "float8_e4m3fn"}
 
+#: accuracy contract of int8 pages: the logits of a tick over int8 pages
+#: lie within INT8_LOGIT_RTOL * max|logit| of the same tick over
+#: unquantized pages (the per-row amax/127 grid moves each cached K/V
+#: entry by at most 0.4% of its row's amax)
+INT8_LOGIT_RTOL = 0.05
+
 
 def _kv_qmax(pages_dtype) -> float:
     return 448.0 if jnp.dtype(pages_dtype).name.startswith("float8") else 127.0
@@ -224,7 +230,7 @@ def gqa_init_paged_cache(cfg, num_pages, page_size, dtype, kv_dtype=""):
     quantized page format: "" stores in ``dtype`` (the engine's
     ``cache_dtype`` — existing path, bit-preserved); "bf16" stores
     bfloat16 with no scales; "int8"/"fp8" store the narrow dtype plus
-    per-page-row (P, page) fp32 ``k_scale``/``v_scale`` pools shared
+    per-page-row (P, 1, page) fp32 ``k_scale``/``v_scale`` pools shared
     across KV heads — 2 bytes/elt -> 1 byte/elt + 4 bytes/row."""
     if kv_dtype not in KV_DTYPES:
         raise ValueError(f"kv_dtype must be one of {KV_DTYPES}, "
@@ -236,8 +242,10 @@ def gqa_init_paged_cache(cfg, num_pages, page_size, dtype, kv_dtype=""):
         "v": jnp.zeros((num_pages, page_size, Hkv, Dh), store),
     }
     if kv_dtype in ("int8", "fp8"):
-        cache["k_scale"] = jnp.ones((num_pages, page_size), jnp.float32)
-        cache["v_scale"] = jnp.ones((num_pages, page_size), jnp.float32)
+        # (P, 1, page): the kernels' (1, 1, page) scale blocks then span
+        # the pool's full last two dims (TPU tiling rule)
+        cache["k_scale"] = jnp.ones((num_pages, 1, page_size), jnp.float32)
+        cache["v_scale"] = jnp.ones((num_pages, 1, page_size), jnp.float32)
     return cache
 
 
@@ -256,21 +264,24 @@ def _gqa_paged_qkv_scatter(p, cfg, x, cache, block_tables, pos, n_valid):
     if "k_scale" in cache:
         k, ks = _quant_rows(k, cache["k"].dtype)
         v, vs = _quant_rows(v, cache["v"].dtype)
-        nc["k_scale"] = paged_scatter(cache["k_scale"], ks, block_tables,
-                                      pos, n_valid, page)
-        nc["v_scale"] = paged_scatter(cache["v_scale"], vs, block_tables,
-                                      pos, n_valid, page)
+        nc["k_scale"] = paged_scatter(cache["k_scale"][:, 0], ks,
+                                      block_tables, pos, n_valid,
+                                      page)[:, None]
+        nc["v_scale"] = paged_scatter(cache["v_scale"][:, 0], vs,
+                                      block_tables, pos, n_valid,
+                                      page)[:, None]
     nc["k"] = paged_scatter(cache["k"], k, block_tables, pos, n_valid, page)
     nc["v"] = paged_scatter(cache["v"], v, block_tables, pos, n_valid, page)
     return q, nc, positions
 
 
 def gqa_paged_apply(p, cfg, x, cache, block_tables, pos, n_valid, *,
-                    window=0):
+                    window=0, kernels="auto"):
     """Chunked decode/prefill against a paged cache.  x: (B, C, D) with C >= 1
     (C == 1 is a decode-only tick; C > 1 serves lanes at ANY phase — per-lane
     ``pos``/``n_valid`` let prefilling lanes advance up to C positions while
-    decoding lanes advance 1 in the same dispatch).  Returns
+    decoding lanes advance 1 in the same dispatch).  ``kernels`` is the
+    plan's kernel path (``ExecutionPlan.kernels``).  Returns
     (out (B,C,D), new_cache)."""
     B, C = x.shape[:2]
     q, nc, positions = _gqa_paged_qkv_scatter(p, cfg, x, cache,
@@ -284,13 +295,15 @@ def gqa_paged_apply(p, cfg, x, cache, block_tables, pos, n_valid, *,
         # pools (quantized KV) ride the same block tables and dequantize
         # inside the kernel's VMEM load
         from repro.kernels import ops
+        kw = ops.kernel_args(kernels)
         if C == 1:
             o = ops.paged_decode_attention(q[:, 0], kc, vc, block_tables,
                                            pos + 1, k_scale=ks,
-                                           v_scale=vs)[:, None]
+                                           v_scale=vs, **kw)[:, None]
         else:
             o = ops.paged_chunk_attention(q, kc, vc, block_tables, pos,
-                                          n_valid, k_scale=ks, v_scale=vs)
+                                          n_valid, k_scale=ks, v_scale=vs,
+                                          **kw)
     else:
         # sliding-window / softcapped layers (gemma2): masked gather path
         o = chunk_attention(q, _gather_dequant(kc, ks, block_tables),
@@ -302,13 +315,13 @@ def gqa_paged_apply(p, cfg, x, cache, block_tables, pos, n_valid, *,
 
 
 def gqa_paged_dual(p, ffn, cfg, x, mlp_in, cache, block_tables, pos,
-                   n_valid):
+                   n_valid, *, kernels="auto"):
     """Dual-branch single-token paged tick: the block-table attention gather
     and the dense FFN matmuls go down as ONE fused dispatch
     (``kernels.ops.dual_branch_decode``) so the TPU overlaps page DMAs with
     FFN MXU work; the CPU fallback runs exactly the sequential path's ops
     (gather-free ref attention + ``layers.mlp_apply``), keeping dual-branch
-    logits bit-identical to sequential decode.
+    logits within a few float32 ulps of sequential decode.
 
     x: (B, 1, D) post-ln1 attention input; mlp_in: (B, 1, D) the block's
     MLP input (independent of this block's attention — the FAL property).
@@ -318,6 +331,7 @@ def gqa_paged_dual(p, ffn, cfg, x, mlp_in, cache, block_tables, pos,
     q, nc, _ = _gqa_paged_qkv_scatter(p, cfg, x, cache, block_tables,
                                       pos, n_valid)
     from repro.kernels import ops
+    kw = ops.kernel_args(kernels)
     if "k_scale" in nc:
         # quantized KV: the fused dual-branch kernel has no dequant path,
         # so issue the two branches as independent ops (XLA still overlaps
@@ -325,18 +339,18 @@ def gqa_paged_dual(p, ffn, cfg, x, mlp_in, cache, block_tables, pos,
         o = ops.paged_decode_attention(q[:, 0], nc["k"], nc["v"],
                                        block_tables, pos + 1,
                                        k_scale=nc["k_scale"],
-                                       v_scale=nc["v_scale"])
+                                       v_scale=nc["v_scale"], **kw)
         y = L.mlp_apply(ffn, mlp_in, cfg.mlp)
     else:
         o, y = ops.dual_branch_decode(q[:, 0], nc["k"], nc["v"],
                                       block_tables, pos + 1, mlp_in, ffn,
-                                      kind=cfg.mlp)
+                                      kind=cfg.mlp, **kw)
     a = o[:, None].reshape(B, C, -1) @ p["wo"].astype(x.dtype)
     return a, y, nc
 
 
 def gqa_packed_apply(p, cfg, x, cache, block_tables, tok_slot, tok_pos, *,
-                     window=0):
+                     window=0, kernels="auto"):
     """Token-packed ragged tick against a paged cache.  x: (1, T, D) — one
     flat buffer of packed tokens where token t belongs to lane
     ``tok_slot[t]`` at logical position ``tok_pos[t]`` (padding tokens at
@@ -354,10 +368,12 @@ def gqa_packed_apply(p, cfg, x, cache, block_tables, tok_slot, tok_pos, *,
     if "k_scale" in cache:
         k, ks_rows = _quant_rows(k, cache["k"].dtype)
         v, vs_rows = _quant_rows(v, cache["v"].dtype)
-        nc["k_scale"] = packed_scatter(cache["k_scale"], ks_rows,
-                                       block_tables, tok_slot, tok_pos, page)
-        nc["v_scale"] = packed_scatter(cache["v_scale"], vs_rows,
-                                       block_tables, tok_slot, tok_pos, page)
+        nc["k_scale"] = packed_scatter(cache["k_scale"][:, 0], ks_rows,
+                                       block_tables, tok_slot, tok_pos,
+                                       page)[:, None]
+        nc["v_scale"] = packed_scatter(cache["v_scale"][:, 0], vs_rows,
+                                       block_tables, tok_slot, tok_pos,
+                                       page)[:, None]
     nc["k"] = packed_scatter(cache["k"], k, block_tables, tok_slot, tok_pos,
                              page)
     nc["v"] = packed_scatter(cache["v"], v, block_tables, tok_slot, tok_pos,
@@ -371,7 +387,8 @@ def gqa_packed_apply(p, cfg, x, cache, block_tables, tok_slot, tok_pos, *,
         from repro.kernels import ops
         o = ops.paged_packed_attention(q[0], kc, vc, block_tables,
                                        tok_slot, tok_pos, k_scale=ks,
-                                       v_scale=vs)[None]
+                                       v_scale=vs,
+                                       **ops.kernel_args(kernels))[None]
     else:
         # sliding-window / softcapped layers (gemma2): per-token masked
         # gather — each token indexes its own slot's gathered sequence
@@ -451,13 +468,12 @@ def sequence_parallel_attention(q, k, v, cfg, plan, *, causal=True,
             cap=cfg.attn_softcap, block_q=min(cfg.attn_block_q, S // M),
             q_offset=off)
 
-    from repro.core.compat import shard_map
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(dax, max_, None, None),
-                             P(dax, None, None, None),
-                             P(dax, None, None, None), P()),
-                   out_specs=P(dax, max_, None, None),
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(dax, max_, None, None),
+                                 P(dax, None, None, None),
+                                 P(dax, None, None, None), P()),
+                       out_specs=P(dax, max_, None, None),
+                       check_vma=False)
     # window may be a traced per-layer scalar (scan xs) — pass explicitly
     return fn(q, k, v, jnp.asarray(window, jnp.int32))
 

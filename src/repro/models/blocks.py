@@ -151,14 +151,16 @@ def block_apply(p, cfg, x, a1_sig, positions, window, *, kind="dense",
             else:
                 a, new_cache = A.gqa_packed_apply(p["attn"], cfg, h, cache,
                                                   block_tables, tok_slot,
-                                                  tok_pos, window=window)
+                                                  tok_pos, window=window,
+                                                  kernels=plan.kernels)
         elif cfg.use_mla:
             a, new_cache = A.mla_paged_apply(p["attn"], cfg, h, cache,
                                              block_tables, pos, n_valid)
         else:
             a, new_cache = A.gqa_paged_apply(p["attn"], cfg, h, cache,
                                              block_tables, pos, n_valid,
-                                             window=window)
+                                             window=window,
+                                             kernels=plan.kernels)
     elif plan.phase is Phase.DECODE:
         if cfg.use_mla:
             a, new_cache = A.mla_decode(p["attn"], cfg, h, cache, pos)
@@ -233,8 +235,9 @@ def _block_apply_dual(p, cfg, x, a1_sig, window, *, kind,
     ``kernels.ops.dual_branch_decode``) that overlaps page DMAs with FFN
     MXU work.  Off the fused-kernel path the arithmetic is op-for-op the
     sequential path's — same primitives, same operands, same residual-merge
-    association — so logits are bit-identical (the fused TPU kernel's tiled
-    accumulation is tolerance-close instead); under explicit TP the two
+    association — so logits match up to XLA fusing the two programs
+    differently (a few float32 ulps; the fused TPU kernel's tiled
+    accumulation is tolerance-close too); under explicit TP the two
     partial sums merge in the SAME single fused all-reduce as the
     sequential fused path (no extra collectives; asserted structurally in
     ``core.tp.make_tp_decode_step`` consumers).
@@ -252,7 +255,8 @@ def _block_apply_dual(p, cfg, x, a1_sig, window, *, kind,
         # layout only — a packed buffer of length 1 is NOT a (B, 1) tick)
         a, y, new_cache = A.gqa_paged_dual(p["attn"], p["ffn"], cfg, h,
                                            mlp_in, cache, block_tables,
-                                           pos, n_valid)
+                                           pos, n_valid,
+                                           kernels=plan.kernels)
         aux = jnp.zeros((), jnp.float32)
     else:
         if plan.phase is Phase.PAGED and tok_slot is not None:
@@ -266,7 +270,8 @@ def _block_apply_dual(p, cfg, x, a1_sig, window, *, kind,
             else:
                 a, new_cache = A.gqa_packed_apply(p["attn"], cfg, h, cache,
                                                   block_tables, tok_slot,
-                                                  tok_pos, window=window)
+                                                  tok_pos, window=window,
+                                                  kernels=plan.kernels)
         elif plan.phase is Phase.PAGED:
             if cfg.use_mla:
                 a, new_cache = A.mla_paged_apply(p["attn"], cfg, h, cache,
@@ -274,7 +279,8 @@ def _block_apply_dual(p, cfg, x, a1_sig, window, *, kind,
             else:
                 a, new_cache = A.gqa_paged_apply(p["attn"], cfg, h, cache,
                                                  block_tables, pos, n_valid,
-                                                 window=window)
+                                                 window=window,
+                                                 kernels=plan.kernels)
         else:
             if cfg.use_mla:
                 a, new_cache = A.mla_decode(p["attn"], cfg, h, cache, pos)
@@ -287,7 +293,7 @@ def _block_apply_dual(p, cfg, x, a1_sig, window, *, kind,
         return (x + _assemble(a + y, axis, plan.grad_compress),
                 a, aux, new_cache)
     # replicated: keep the sequential path's (x + a) + y association so
-    # dual-branch logits are bit-identical, not merely close
+    # dual-branch logits differ from sequential ones only by XLA fusion
     return (x + a) + y, a, aux, new_cache
 
 

@@ -163,7 +163,6 @@ def decoder_stack_tp(p, cfg, x, positions, plan: ExecutionPlan):
     signal; under SP each all-reduce becomes a reduce-scatter at 1/tp the
     bytes behind an all-gather of the LN region.  Returns (x, aux)."""
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import shard_map
     from repro.launch import mesh as MX
 
     plan.validate(cfg)
@@ -194,10 +193,10 @@ def decoder_stack_tp(p, cfg, x, positions, plan: ExecutionPlan):
             aux = jax.lax.pmean(aux, dax)
         return x, aux
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(wspecs, P(b_ax, s_ax, None), P(b_ax, None)),
-                   out_specs=(P(b_ax, s_ax, None), P()),
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(wspecs, P(b_ax, s_ax, None), P(b_ax, None)),
+                       out_specs=(P(b_ax, s_ax, None), P()),
+                       check_vma=False)
     return fn(blocks, x, positions)
 
 
@@ -1051,7 +1050,7 @@ def lm_head_segment_tail(params, cfg, hidden, seg_last, n):
     return _logits(params, cfg, h), rows
 
 
-def copy_paged_pages(cache, src, dst):
+def copy_paged_pages(cache, src, dst, *, kernels="auto"):
     """Copy-on-write page duplication across EVERY layer's KV pools: the
     page rows at physical pages ``src`` (n,) are copied over pages ``dst``
     (n,) in block 0's pools and all stacked upper-layer pools — the device
@@ -1061,13 +1060,15 @@ def copy_paged_pages(cache, src, dst):
     ``ops.copy_pages`` dispatch each by viewing them as (L-1)*P flat pages
     and offsetting the page ids per layer.  The ``a1_sig`` buffer is
     per-slot, not per-page — untouched.  Callers jit this with the cache
-    donated (the Pallas path aliases the pools in place)."""
+    donated (the Pallas path aliases the pools in place).  ``kernels`` is
+    the plan's kernel path (``ExecutionPlan.kernels``)."""
     from repro.kernels import ops as _ops
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
+    kw = _ops.kernel_args(kernels)
 
     def one(pool):
-        return _ops.copy_pages(pool, src, dst)
+        return _ops.copy_pages(pool, src, dst, **kw)
 
     def stacked(pool):
         L, P = pool.shape[0], pool.shape[1]
@@ -1075,7 +1076,7 @@ def copy_paged_pages(cache, src, dst):
         off = (jnp.arange(L, dtype=jnp.int32) * P)[:, None]
         s = (src[None, :] + off).reshape(-1)
         d = (dst[None, :] + off).reshape(-1)
-        return _ops.copy_pages(flat, s, d).reshape(pool.shape)
+        return _ops.copy_pages(flat, s, d, **kw).reshape(pool.shape)
 
     new = dict(cache)
     new["block0"] = jax.tree.map(one, cache["block0"])

@@ -23,7 +23,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import shard_map
 import numpy as np
 
 from repro.models import layers as L
@@ -216,7 +215,7 @@ def moe_apply_sharded(p, cfg, x, plan):
                 None if shared is None else jax.tree.map(lambda _: P(), shared),
                 P(data_axes, model_axis, None))
     out_specs = (P(data_axes, model_axis, None), P())
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return fn(p["router"], p["wi"], p["wg"], p["wo"], shared, x)
 
@@ -338,6 +337,6 @@ def moe_apply_shard_slot(p, cfg, x, plan):
                 None if shared is None else jax.tree.map(lambda _: P(), shared),
                 P(data_axes, model_axis, None))
     out_specs = (P(data_axes, model_axis, None), P())
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return fn(p["router"], p["wi"], p["wg"], p["wo"], shared, x)

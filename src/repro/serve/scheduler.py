@@ -82,6 +82,7 @@ DECODE -> PREEMPTED/requeued -> FINISHED) as Chrome trace-event JSON.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import List, Optional
 
@@ -403,10 +404,10 @@ class EngineConfig:
     kv_dtype: str = ""
     # MHA||MLP branch-parallel decode dispatch off the cached per-slot FAL
     # signal (plan.dual_branch; fal/parallel-family connections only —
-    # ExecutionPlan.validate rejects the rest).  Logits are bit-identical
-    # to sequential decode on the CPU dispatch path (the fused TPU kernel
-    # is tolerance-close); the win is overlap of the paged KV gather with
-    # the FFN matmuls.
+    # ExecutionPlan.validate rejects the rest).  Logits match sequential
+    # decode to a few float32 ulps on the CPU dispatch path (the fused TPU
+    # kernel is tolerance-close); the win is overlap of the paged KV
+    # gather with the FFN matmuls.
     dual_branch: bool = False
     # radix prefix cache over page-aligned finished prefixes: admission
     # longest-prefix matches the prompt, shares the cached pages into the
@@ -508,7 +509,10 @@ class PagedEngine:
                 metrics=self.metrics, tracer=self.tracer)
             # per-page device memcpy across every layer's pools; the cache
             # is donated so the Pallas path rewrites the pools in place
-            self._cow_fn = jax.jit(M.copy_paged_pages, donate_argnums=(0,))
+            self._cow_fn = jax.jit(
+                functools.partial(M.copy_paged_pages,
+                                  kernels=self.plan.kernels),
+                donate_argnums=(0,))
         self.slots: List[Optional[ServeRequest]] = [None] * engine_cfg.slots
         self.queue: List[ServeRequest] = []
         self.finished: List[ServeRequest] = []

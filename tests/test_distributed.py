@@ -39,7 +39,8 @@ def test_tp_allreduce_halving():
     out = run_py("""
 import jax, jax.numpy as jnp, json
 from repro.core import tp
-mesh = jax.make_mesh((8,), ('model',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('model',))
 res = {}
 for mode in ['preln', 'fal', 'parallel', 'falplus', 'ablation1', 'ablation2']:
     init, fwd = tp.make_tp_forward(mesh, 4, 64, 256, 8, mode)
@@ -70,7 +71,8 @@ def test_sp_reduce_scatter_structure():
     out = run_py("""
 import jax, jax.numpy as jnp, json
 from repro.core import tp
-mesh = jax.make_mesh((8,), ('model',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ('model',))
 res = {}
 for mode in ['preln', 'fal', 'parallel', 'falplus']:
     row = {}
@@ -110,8 +112,9 @@ def test_tp_forward_matches_replicated():
     out = run_py("""
 import jax, jax.numpy as jnp
 from repro.core import tp
-mesh1 = jax.make_mesh((1,), ('model',))
-mesh8 = jax.make_mesh((8,), ('model',))
+from repro.launch.mesh import make_mesh
+mesh1 = make_mesh((1,), ('model',))
+mesh8 = make_mesh((8,), ('model',))
 x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 64))
 for mode in ['preln', 'fal']:
     init1, fwd1 = tp.make_tp_forward(mesh1, 3, 64, 256, 8, mode)
@@ -135,7 +138,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config, VALID_CONNECTIONS
 from repro.core.plan import ExecutionPlan
 from repro.models import model as M
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 500)
 for sp in (False, True):
     for mode in VALID_CONNECTIONS:
@@ -167,7 +171,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config
 from repro.core.plan import ExecutionPlan
 from repro.models import model as M
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 500)
 cases = [('qwen3-moe-30b-a3b', {}),
          ('deepseek-v3-671b', {}),
@@ -203,7 +208,8 @@ from repro.optim import adamw
 from repro.train import step as tstep
 cfg = get_config('llama3.2-3b').reduced().replace(
     connection='fal', n_kv_heads=4)
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 ocfg = adamw.AdamWConfig(lr=1e-3)
 state = tstep.init_state(jax.random.PRNGKey(0), cfg, ocfg)
 batch = {'tokens': jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
@@ -235,7 +241,8 @@ cfg = get_config('qwen3-moe-30b-a3b').reduced().replace(
     n_experts=8, top_k=2, capacity_factor=8.0)
 p = MO.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 32, cfg.d_model)) * 0.5
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 plan = ExecutionPlan.from_mesh(mesh)
 y_ref, _ = MO.moe_apply(p, cfg, x)
 f = jax.jit(lambda p, x: MO.moe_apply_sharded(p, cfg, x, plan))
@@ -265,7 +272,8 @@ cfg = get_config('llama3.2-3b').reduced().replace(connection='fal')
 params = M.init_params(jax.random.PRNGKey(0), cfg)
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab)
 ref, _, _ = M.forward(params, cfg, {'tokens': toks})
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 plan = ExecutionPlan.from_mesh(mesh)          # implicit GSPMD
 specs = MX.param_specs(params, cfg)
 sh = MX.shardings_for(mesh, specs)
@@ -301,7 +309,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config
 from repro.core.plan import ExecutionPlan
 from repro.models import model as M
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 plan = ExecutionPlan.from_mesh(mesh)
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, 500)
 for arch in ['llama3.2-3b', 'gemma2-27b', 'deepseek-v3-671b']:
@@ -332,7 +341,8 @@ cfg = get_config('qwen3-moe-30b-a3b').reduced().replace(
     route_groups=4, route_group_limit=2)
 p = MO.moe_init(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 32, cfg.d_model)) * 0.5
-mesh = jax.make_mesh((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ('data', 'model'))
 plan = ExecutionPlan.from_mesh(mesh)
 y_ref, _ = MO.moe_apply(p, cfg, x)
 y_sh, _ = jax.jit(lambda p, x: MO.moe_apply_shard_slot(

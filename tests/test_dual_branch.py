@@ -1,5 +1,5 @@
-"""Dual-branch (MHA||MLP) decode: bit-exact logits equivalence vs the
-sequential path across connection modes and decoder families, loud
+"""Dual-branch (MHA||MLP) decode: logits equivalence vs the sequential
+path across connection modes and decoder families, loud
 ``ExecutionPlan.validate`` errors for modes/phases where the branches cannot
 run concurrently, the fused Pallas dispatch vs its oracle, and the
 structural no-extra-collectives gate under explicit TP."""
@@ -24,6 +24,19 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # the six styles split exactly on fal.mlp_input_depends_on_local_attention
 DUAL_MODES = ("fal", "parallel", "ablation2")
 SEQ_ONLY_MODES = ("preln", "falplus", "ablation1")
+
+#: the sequential and dual-branch paged ticks compute the same float32
+#: arithmetic, but XLA fuses the two programs differently and may reorder
+#: float32 accumulations (fal's fresh-signal add, the MLA latent matmuls).
+#: The logits then differ by a few ulps (at most 1.5e-6 at |logit| <= 4.5
+#: on these reduced configs); a real dispatch bug moves them by O(1e-1).
+LOGIT_ATOL = 2e-5
+
+
+def _assert_logits_close(seq, dual, what=""):
+    err = float(jnp.max(jnp.abs(seq - dual)))
+    assert err <= LOGIT_ATOL, (what, err)
+
 
 FAMILY_ARCHS = [("llama3.2-3b", "dense"),
                 ("qwen3-moe-30b-a3b", "moe"),
@@ -57,17 +70,17 @@ def _paged_logits(cfg, params, toks, chunk, *, dual, page_size=8,
 @pytest.mark.parametrize("arch,family", FAMILY_ARCHS)
 @pytest.mark.parametrize("mode", DUAL_MODES)
 def test_dual_branch_bit_exact_paged(arch, family, mode):
-    """Dual-branch paged decode must be BIT-IDENTICAL to sequential decode
-    (same primitives, same operands, same residual-merge association) for
-    every dual-eligible style x decoder family."""
+    """Dual-branch paged decode must match sequential decode (same
+    primitives, same operands, same residual-merge association; only
+    XLA's fusion differs — ``LOGIT_ATOL``) for every dual-eligible style x
+    decoder family."""
     cfg = get_config(arch).reduced().replace(connection=mode)
     assert cfg.family == family
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, cfg.vocab)
     seq = _paged_logits(cfg, params, toks, chunk=1, dual=False)
     dual = _paged_logits(cfg, params, toks, chunk=1, dual=True)
-    assert bool(jnp.array_equal(seq, dual)), (
-        arch, mode, float(jnp.max(jnp.abs(seq - dual))))
+    _assert_logits_close(seq, dual, (arch, mode))
 
 
 def test_dual_branch_bit_exact_chunked_prefill():
@@ -84,8 +97,9 @@ def test_dual_branch_bit_exact_chunked_prefill():
 
 def test_dual_branch_bit_exact_reduced_cache_dtype():
     """Active lanes must consume this tick's FRESH activation-dtype signal —
-    routing it through a bfloat16 KV-cache dtype would round it and break
-    bit-identity with the sequential path (regression)."""
+    routing it through a bfloat16 KV-cache dtype would round it (an error
+    of bf16's 2^-8, far above ``LOGIT_ATOL``) and break equivalence with
+    the sequential path (regression)."""
     cfg = get_config("llama3.2-3b").reduced().replace(connection="fal")
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     B, S, page = 2, 12, 8
@@ -107,7 +121,7 @@ def test_dual_branch_bit_exact_reduced_cache_dtype():
             outs.append(lg)
         return jnp.concatenate(outs, 1)
 
-    assert bool(jnp.array_equal(drive(False), drive(True)))
+    _assert_logits_close(drive(False), drive(True))
 
 
 def test_dual_branch_bit_exact_contiguous_decode():
@@ -133,13 +147,13 @@ def test_dual_branch_bit_exact_contiguous_decode():
 
 def test_dual_branch_mla_paged():
     """MLA (latent pages) has no fused kernel but still runs branch-parallel
-    dispatch; bit-exactness must hold there as well."""
+    dispatch; equivalence within ``LOGIT_ATOL`` must hold there as well."""
     cfg = get_config("deepseek-v3-671b").reduced().replace(connection="fal")
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 10), 0, cfg.vocab)
     seq = _paged_logits(cfg, params, toks, chunk=1, dual=False)
     dual = _paged_logits(cfg, params, toks, chunk=1, dual=True)
-    assert bool(jnp.array_equal(seq, dual))
+    _assert_logits_close(seq, dual)
 
 
 # --------------------------------------------------------------------------- #
@@ -224,11 +238,13 @@ def test_engine_rejects_dual_branch_with_preln():
 @pytest.mark.parametrize("kind", ["swiglu", "geglu", "gelu"])
 def test_fused_dual_branch_kernel_matches_oracle(kind):
     """Interpret-mode fused kernel (paged gather + FFN tiles in one
-    pallas_call) vs the gather ref + mlp_apply oracle."""
+    pallas_call) vs the gather ref + mlp_apply oracle.  d_ff splits into
+    Hkv*T = 8 tiles of 128 lanes, so the dispatcher selects the fused
+    kernel."""
     from repro.kernels import ops, ref as R
     from repro.models.layers import mlp_apply, mlp_init
     ks = jax.random.split(jax.random.PRNGKey(9), 6)
-    B, H, Hkv, D, page, T, Dm, F = 2, 8, 2, 32, 8, 4, 64, 256
+    B, H, Hkv, D, page, T, Dm, F = 2, 8, 2, 32, 8, 4, 64, 1024
     q = jax.random.normal(ks[0], (B, H, D))
     kp = jax.random.normal(ks[1], (T * B + 2, page, Hkv, D))
     vp = jax.random.normal(ks[2], (T * B + 2, page, Hkv, D))
@@ -236,8 +252,10 @@ def test_fused_dual_branch_kernel_matches_oracle(kind):
     sl = jnp.asarray([(T - 1) * page + 3, page], jnp.int32)
     x = jax.random.normal(ks[3], (B, 1, Dm))
     ffn = mlp_init(ks[4], Dm, F, kind)
+    ops.reset_dispatch_paths()
     a, y = ops.dual_branch_decode(q, kp, vp, bt, sl, x, ffn, kind=kind,
                                   interpret=True)
+    assert ops.dispatch_paths() == {"dual_branch_decode": "interpret"}
     a_ref = R.paged_attention_ref(q, kp, vp, bt, sl)
     y_ref = mlp_apply(ffn, x, kind)
     assert jnp.max(jnp.abs(a - a_ref)) < 2e-5
@@ -246,7 +264,7 @@ def test_fused_dual_branch_kernel_matches_oracle(kind):
 
 def test_fused_kernel_falls_back_on_non_divisible_dff():
     """d_ff not divisible into Hkv*T tiles -> dispatcher issues the two
-    branches separately instead of erroring."""
+    branches separately instead of erroring, under their own label."""
     from repro.kernels import ops
     from repro.models.layers import mlp_init
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
@@ -257,8 +275,10 @@ def test_fused_kernel_falls_back_on_non_divisible_dff():
     sl = jnp.asarray([9], jnp.int32)
     x = jax.random.normal(ks[3], (1, 1, 48))
     ffn = mlp_init(ks[4], 48, 98, "gelu")       # 98 % (Hkv*T = 2*2) != 0
+    ops.reset_dispatch_paths()
     a, y = ops.dual_branch_decode(q, kp, vp, bt, sl, x, ffn, kind="gelu",
                                   interpret=True)
+    assert ops.dispatch_paths() == {"dual_branch_decode.two_op": "interpret"}
     assert a.shape == (1, 4, 32) and y.shape == (1, 1, 48)
     # and the separate-branch results still match the oracles
     from repro.kernels import ref as R
@@ -280,7 +300,8 @@ def test_dual_branch_no_extra_collectives_explicit_tp():
     script = """
 import jax
 from repro.core import tp
-mesh = jax.make_mesh((2,), ('model',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ('model',))
 counts = tp.assert_dual_no_extra_collectives(mesh, modes=('fal', 'parallel'))
 assert set(counts) == {'fal', 'parallel'}
 print('OK', counts)

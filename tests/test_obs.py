@@ -291,7 +291,7 @@ def test_kernel_dispatch_paths_runtime_measured():
            f"{paths['paged_packed_attention']}"
     assert default_registry().counter(name).value >= 1
     # engine stats' dispatch telemetry and BENCH stamping both read this map
-    assert set(paths.values()) <= {"fused-tpu", "cpu-fallback"}
+    assert set(paths.values()) <= {"fused-tpu", "interpret", "cpu-fallback"}
 
 
 def test_run_metadata_shape():

@@ -37,6 +37,21 @@ def test_single_device_defaults():
     p.validate(cfg_for())      # nothing to reject
 
 
+@pytest.mark.parametrize("kernels", ["auto", "pallas", "interpret", "ref"])
+def test_kernels_paths_map_to_dispatcher_args(kernels):
+    from repro.kernels import ops
+    p = ExecutionPlan.single_device().with_kernels(kernels)
+    assert p.validate(cfg_for()).kernels == kernels
+    args = ops.kernel_args(kernels)
+    assert set(args) <= {"use_pallas", "interpret"}
+
+
+def test_unknown_kernels_path_rejected():
+    with pytest.raises(ValueError, match="unknown kernels"):
+        ExecutionPlan.single_device().with_kernels("mosaic").validate(
+            cfg_for())
+
+
 def test_from_mesh_axes_and_styles():
     mesh = fake_mesh(pod=2, data=4, model=8)
     p = ExecutionPlan.from_mesh(mesh, tp="explicit")
@@ -129,7 +144,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.configs.base import get_config
 from repro.core.plan import ExecutionPlan
 from repro.models import model as M
-mesh = jax.make_mesh((2,), ('model',))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ('model',))
 toks = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 500)
 for mode in ('preln', 'fal', 'falplus'):
     cfg = get_config('llama3.2-3b').reduced().replace(connection=mode)

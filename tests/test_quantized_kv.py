@@ -3,7 +3,7 @@ correctness against the fp32 oracle, COW scale preservation, and
 engine-level greedy/prefix-hit token identity across connection styles.
 
 The format under test: int8/fp8 K/V pools (P, page, Hkv, Dh) plus
-(P, page) fp32 ``k_scale``/``v_scale`` pools — ONE scale per cached token
+(P, 1, page) fp32 ``k_scale``/``v_scale`` pools — ONE scale per cached token
 row, shared across KV heads, history-free (a row's scale depends only on
 that row's values), so COW page copies and prefix-cache shares stay
 bit-exact and idempotent.
@@ -57,15 +57,15 @@ def test_quantized_oracle_matches_manual_dequant():
     ks = jax.random.split(key, 6)
     kp = jax.random.randint(ks[0], (P, page, Hkv, Dh), -127, 128, jnp.int8)
     vp = jax.random.randint(ks[1], (P, page, Hkv, Dh), -127, 128, jnp.int8)
-    ksc = jax.random.uniform(ks[2], (P, page), minval=0.005, maxval=0.05)
-    vsc = jax.random.uniform(ks[3], (P, page), minval=0.005, maxval=0.05)
+    ksc = jax.random.uniform(ks[2], (P, 1, page), minval=0.005, maxval=0.05)
+    vsc = jax.random.uniform(ks[3], (P, 1, page), minval=0.005, maxval=0.05)
     bt = jnp.arange(1, 1 + B * T).reshape(B, T)
     q = jax.random.normal(ks[4], (B, H, Dh))
     seq = jnp.array([9, 20])
 
     def dq(pages, sc):
         return (pages.astype(jnp.float32)
-                * sc[:, :, None, None]).astype(jnp.float32)
+                * sc[:, 0, :, None, None]).astype(jnp.float32)
 
     got = ref.paged_attention_ref(q, kp, vp, bt, seq, k_scale=ksc,
                                   v_scale=vsc)
@@ -91,8 +91,8 @@ def test_quantized_kernels_match_oracles_interpret(store):
             jnp.float8_e4m3fn)
         vp = jax.random.normal(ks[1], (P, page, Hkv, Dh)).astype(
             jnp.float8_e4m3fn)
-    ksc = jax.random.uniform(ks[2], (P, page), minval=0.005, maxval=0.02)
-    vsc = jax.random.uniform(ks[3], (P, page), minval=0.005, maxval=0.02)
+    ksc = jax.random.uniform(ks[2], (P, 1, page), minval=0.005, maxval=0.02)
+    vsc = jax.random.uniform(ks[3], (P, 1, page), minval=0.005, maxval=0.02)
     bt = jax.random.permutation(ks[4], jnp.arange(1, P))[:B * T].reshape(B, T)
 
     q = jax.random.normal(ks[5], (B, H, Dh))
@@ -146,7 +146,7 @@ def test_quantized_logit_error_bounded():
         out[kv] = np.asarray(logits)
     err = np.max(np.abs(out["int8"] - out[""]))
     ref_mag = np.max(np.abs(out[""]))
-    assert err < 0.05 * ref_mag, (err, ref_mag)
+    assert err < A.INT8_LOGIT_RTOL * ref_mag, (err, ref_mag)
 
 
 # --------------------------------------------------------------------------- #
@@ -162,8 +162,9 @@ def test_init_paged_cache_kv_dtypes():
         assert str(c["block0"]["k"].dtype) == dt, kv
         assert ("k_scale" in c["block0"]) == scaled, kv
         if scaled:
-            assert c["block0"]["k_scale"].shape == (8, 8)
-            assert c["blocks"]["v_scale"].shape == (cfg.n_layers - 1, 8, 8)
+            assert c["block0"]["k_scale"].shape == (8, 1, 8)
+            assert c["blocks"]["v_scale"].shape == (cfg.n_layers - 1, 8, 1,
+                                                    8)
     with pytest.raises(ValueError):
         M.init_paged_cache(cfg, 8, 8, 2, "float32", kv_dtype="int4")
 
@@ -176,11 +177,11 @@ def test_quantized_kv_rejected_for_mla():
 
 
 def test_page_copy_preserves_scales_bit_exact():
-    """COW over a quantized cache: the (P, page) scale pools ride the same
+    """COW over a quantized cache: the (P, 1, page) scale pools ride the same
     page-copy as the K/V pools, and the copied rows are bit-identical."""
     key = jax.random.PRNGKey(4)
     P, page = 10, 8
-    sc = jax.random.uniform(key, (P, page), minval=1e-4, maxval=2.0)
+    sc = jax.random.uniform(key, (P, 1, page), minval=1e-4, maxval=2.0)
     src = jnp.array([2, 5])
     dst = jnp.array([7, 9])
     want = ref.copy_pages_ref(sc, src, dst)
