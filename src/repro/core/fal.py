@@ -30,6 +30,7 @@ path is the same code with the assemble reduced over nothing (tp_size = 1).
 from __future__ import annotations
 
 from repro.models import layers as L
+from repro.obs.trace import layer_scope
 
 # modes whose MLP input requires the *assembled* (post all-reduce) attention
 # output of the SAME block:
@@ -77,7 +78,8 @@ def first_attention_signal(cfg, block0_params, a1_raw):
     FAL+: export the raw tensor; each block applies its own ``ln_fal``.
     """
     if cfg.connection == "fal":
-        return L.norm_apply(block0_params["ln_a"], a1_raw, cfg.norm)
+        with layer_scope("fal_signal"):
+            return L.norm_apply(block0_params["ln_a"], a1_raw, cfg.norm)
     if cfg.connection == "falplus":
         return a1_raw
     return None

@@ -186,6 +186,7 @@ def fused_dual_branch_decode(q, k_pages, v_pages, block_tables, seq_lens,
                                kind=kind)
     out, ffn_out = pl.pallas_call(
         kernel,
+        name="fused_dual_branch_decode",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B, 1, Dm), x.dtype)],

@@ -107,6 +107,7 @@ def flash_attention(q, k, v, *, causal=True, block_q=128, block_k=128,
                                block_q=block_q, block_k=block_k, seq_k=Sk)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),

@@ -54,6 +54,7 @@ def fused_ln_add(x, a1n, scale, bias=None, *, kind="rmsnorm", eps=1e-6,
     kernel = functools.partial(_ln_add_kernel, eps=eps, kind=kind)
     out = pl.pallas_call(
         kernel,
+        name="fused_ln_add",
         grid=(n,),
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),

@@ -153,6 +153,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
                                quantized=quantized)
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
         interpret=interpret,
@@ -282,6 +283,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, pos, n_valid, *,
                                page_size=page, G=G, quantized=quantized)
     out = pl.pallas_call(
         kernel,
+        name="paged_chunk_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, C * G, Dv), q.dtype),
         interpret=interpret,
@@ -405,6 +407,7 @@ def paged_packed_attention(q, k_pages, v_pages, block_tables, tok_slot,
                                page_size=page, quantized=quantized)
     out = pl.pallas_call(
         kernel,
+        name="paged_packed_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, Hkv, G, Dv), q.dtype),
         interpret=interpret,
@@ -451,6 +454,7 @@ def page_copy(pool, src, dst, *, interpret=False):
     )
     out = pl.pallas_call(
         _page_copy_kernel,
+        name="page_copy",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype),
         input_output_aliases={2: 0},

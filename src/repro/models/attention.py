@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import layers as L
+from repro.obs.trace import layer_scope
 
 
 # ------------------------------------------------------------------------- #
@@ -111,19 +112,22 @@ def paged_scatter(pages, vals, block_tables, pos, n_valid, page_size):
     pages: (P, page_size, ...); vals: (B, C, ...); block_tables: (B, T);
     pos: (B,) logical position of each request's first chunk token;
     n_valid: (B,) number of valid tokens in the chunk (rest -> scratch page).
+    Runs under the ``kv_write`` layer scope.
     """
     B, C = vals.shape[:2]
     T = block_tables.shape[1]
-    lpos = pos[:, None] + jnp.arange(C)[None]                     # (B, C)
-    blk = jnp.clip(lpos // page_size, 0, T - 1)
-    pg = jnp.take_along_axis(block_tables, blk, axis=1)           # (B, C)
-    valid = jnp.arange(C)[None] < n_valid[:, None]
-    pg = jnp.where(valid, pg, 0)                                  # scratch
-    flat_idx = (pg * page_size + lpos % page_size).reshape(-1)
-    flat = pages.reshape((pages.shape[0] * page_size,) + pages.shape[2:])
-    flat = flat.at[flat_idx].set(
-        vals.reshape((B * C,) + vals.shape[2:]).astype(pages.dtype))
-    return flat.reshape(pages.shape)
+    with layer_scope("kv_write"):
+        lpos = pos[:, None] + jnp.arange(C)[None]                 # (B, C)
+        blk = jnp.clip(lpos // page_size, 0, T - 1)
+        pg = jnp.take_along_axis(block_tables, blk, axis=1)       # (B, C)
+        valid = jnp.arange(C)[None] < n_valid[:, None]
+        pg = jnp.where(valid, pg, 0)                              # scratch
+        flat_idx = (pg * page_size + lpos % page_size).reshape(-1)
+        flat = pages.reshape((pages.shape[0] * page_size,)
+                             + pages.shape[2:])
+        flat = flat.at[flat_idx].set(
+            vals.reshape((B * C,) + vals.shape[2:]).astype(pages.dtype))
+        return flat.reshape(pages.shape)
 
 
 def packed_scatter(pages, vals, block_tables, tok_slot, tok_pos, page_size):
@@ -133,17 +137,20 @@ def packed_scatter(pages, vals, block_tables, tok_slot, tok_pos, page_size):
     block_tables: (S, Tb) per-SLOT tables; tok_slot/tok_pos: (T,) — token t
     belongs to lane ``tok_slot[t]`` at logical position ``tok_pos[t]``.
     Padding tokens carry tok_pos == -1 and are redirected to the scratch
-    page (page 0), so ragged packs never corrupt live pages.
+    page (page 0), so ragged packs never corrupt live pages.  Runs under
+    the ``kv_write`` scope, like ``paged_scatter``.
     """
     Tb = block_tables.shape[1]
-    pos = jnp.maximum(tok_pos, 0)
-    blk = jnp.clip(pos // page_size, 0, Tb - 1)
-    pg = block_tables[tok_slot, blk]                              # (T,)
-    pg = jnp.where(tok_pos >= 0, pg, 0)                           # scratch
-    flat_idx = pg * page_size + pos % page_size
-    flat = pages.reshape((pages.shape[0] * page_size,) + pages.shape[2:])
-    flat = flat.at[flat_idx].set(vals.astype(pages.dtype))
-    return flat.reshape(pages.shape)
+    with layer_scope("kv_write"):
+        pos = jnp.maximum(tok_pos, 0)
+        blk = jnp.clip(pos // page_size, 0, Tb - 1)
+        pg = block_tables[tok_slot, blk]                          # (T,)
+        pg = jnp.where(tok_pos >= 0, pg, 0)                       # scratch
+        flat_idx = pg * page_size + pos % page_size
+        flat = pages.reshape((pages.shape[0] * page_size,)
+                             + pages.shape[2:])
+        flat = flat.at[flat_idx].set(vals.astype(pages.dtype))
+        return flat.reshape(pages.shape)
 
 
 def paged_gather(pages, block_tables):

@@ -22,6 +22,7 @@ from repro.core.plan import ExecutionPlan, Phase
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
+from repro.obs.trace import layer_scope
 from repro.optim import grad_compress as GC
 
 
@@ -64,24 +65,69 @@ def _assemble(partial, axis, compress="none"):
     ``optim.grad_compress.compressed_psum`` — forward still exact."""
     if axis is None:
         return partial
-    if compress != "none":
-        return GC.compressed_psum(partial, axis, compress)
-    return jax.lax.psum(partial, axis)
+    with layer_scope("assemble"):
+        if compress != "none":
+            return GC.compressed_psum(partial, axis, compress)
+        return jax.lax.psum(partial, axis)
+
+
+def _self_attention(p, cfg, x, positions, window, plan: ExecutionPlan, *,
+                    causal=True, cache=None, pos=None, block_tables=None,
+                    n_valid=None, tok_slot=None, tok_pos=None):
+    """ln1 and the self-attention of ``plan.phase`` (full sequence,
+    single-token decode, paged padded or token-packed), under the ``attn``
+    scope.  Returns (a, new_cache); ``new_cache`` is None for the
+    full-sequence phases."""
+    with layer_scope("attn"):
+        h = L.norm_apply(p["ln1"], x, cfg.norm)
+        if plan.phase is Phase.PAGED:
+            if tok_slot is not None:
+                if cfg.use_mla:
+                    return A.mla_packed_apply(p["attn"], cfg, h, cache,
+                                              block_tables, tok_slot, tok_pos)
+                return A.gqa_packed_apply(p["attn"], cfg, h, cache,
+                                          block_tables, tok_slot, tok_pos,
+                                          window=window, kernels=plan.kernels)
+            if cfg.use_mla:
+                return A.mla_paged_apply(p["attn"], cfg, h, cache,
+                                         block_tables, pos, n_valid)
+            return A.gqa_paged_apply(p["attn"], cfg, h, cache, block_tables,
+                                     pos, n_valid, window=window,
+                                     kernels=plan.kernels)
+        if plan.phase is Phase.DECODE:
+            if cfg.use_mla:
+                return A.mla_decode(p["attn"], cfg, h, cache, pos)
+            return A.gqa_decode(p["attn"], cfg, h, cache, pos, window=window)
+        if cfg.use_mla:
+            return A.mla_apply(p["attn"], cfg, h, positions, plan=plan), None
+        return A.gqa_apply(p["attn"], cfg, h, positions, window=window,
+                           causal=causal, plan=plan), None
+
+
+def _mlp_input(cfg, p, x, a, a1_sig, is_block0):
+    """The block's MLP input under the connection mode (``core.fal``),
+    under the ``fal_signal`` scope."""
+    with layer_scope("fal_signal"):
+        if is_block0:
+            return fal.block0_mlp_input(cfg, p, x, a)
+        return fal.mlp_input(cfg, p, x, a, a1_sig)
 
 
 def _ffn_apply(p, cfg, h, kind, plan: ExecutionPlan):
-    """Returns (y, aux).  Under explicit TP ``y`` is a PARTIAL sum (dense:
-    column-sharded wi/wg, row-sharded wo; MoE: local experts only)."""
-    if kind == "moe":
-        axis = plan.tp_axis
-        if axis is not None:
-            return M.moe_apply_partial(p["ffn"], cfg, h, axis)
-        if plan.is_training_like and plan.is_sharded:
-            fn = (M.moe_apply_shard_slot if cfg.route_groups
-                  else M.moe_apply_sharded)
-            return fn(p["ffn"], cfg, h, plan)
-        return M.moe_apply(p["ffn"], cfg, h)
-    return L.mlp_apply(p["ffn"], h, cfg.mlp), jnp.zeros((), jnp.float32)
+    """Returns (y, aux), under the ``mlp`` scope.  Under explicit TP ``y``
+    is a PARTIAL sum (dense: column-sharded wi/wg, row-sharded wo; MoE:
+    local experts only)."""
+    with layer_scope("mlp"):
+        if kind == "moe":
+            axis = plan.tp_axis
+            if axis is not None:
+                return M.moe_apply_partial(p["ffn"], cfg, h, axis)
+            if plan.is_training_like and plan.is_sharded:
+                fn = (M.moe_apply_shard_slot if cfg.route_groups
+                      else M.moe_apply_sharded)
+                return fn(p["ffn"], cfg, h, plan)
+            return M.moe_apply(p["ffn"], cfg, h)
+        return L.mlp_apply(p["ffn"], h, cfg.mlp), jnp.zeros((), jnp.float32)
 
 
 def block_apply(p, cfg, x, a1_sig, positions, window, *, kind="dense",
@@ -140,39 +186,11 @@ def block_apply(p, cfg, x, a1_sig, positions, window, *, kind="dense",
         return _block_apply_sp(p, cfg, x, a1_sig, positions, window,
                                kind=kind, is_block0=is_block0, plan=plan)
 
-    h = L.norm_apply(p["ln1"], x, cfg.norm)
-    new_cache = None
-    if plan.phase is Phase.PAGED:
-        if tok_slot is not None:
-            if cfg.use_mla:
-                a, new_cache = A.mla_packed_apply(p["attn"], cfg, h, cache,
-                                                  block_tables, tok_slot,
-                                                  tok_pos)
-            else:
-                a, new_cache = A.gqa_packed_apply(p["attn"], cfg, h, cache,
-                                                  block_tables, tok_slot,
-                                                  tok_pos, window=window,
-                                                  kernels=plan.kernels)
-        elif cfg.use_mla:
-            a, new_cache = A.mla_paged_apply(p["attn"], cfg, h, cache,
-                                             block_tables, pos, n_valid)
-        else:
-            a, new_cache = A.gqa_paged_apply(p["attn"], cfg, h, cache,
-                                             block_tables, pos, n_valid,
-                                             window=window,
-                                             kernels=plan.kernels)
-    elif plan.phase is Phase.DECODE:
-        if cfg.use_mla:
-            a, new_cache = A.mla_decode(p["attn"], cfg, h, cache, pos)
-        else:
-            a, new_cache = A.gqa_decode(p["attn"], cfg, h, cache, pos,
-                                        window=window)
-    else:
-        if cfg.use_mla:
-            a = A.mla_apply(p["attn"], cfg, h, positions, plan=plan)
-        else:
-            a = A.gqa_apply(p["attn"], cfg, h, positions, window=window,
-                            causal=causal, plan=plan)
+    a, new_cache = _self_attention(p, cfg, x, positions, window, plan,
+                                   causal=causal, cache=cache, pos=pos,
+                                   block_tables=block_tables,
+                                   n_valid=n_valid, tok_slot=tok_slot,
+                                   tok_pos=tok_pos)
     axis = plan.tp_axis
     # post-norms and cross-attention normalise/consume the true ``a`` —
     # nonlinear in the partial, so they force the assembled path
@@ -182,10 +200,7 @@ def block_apply(p, cfg, x, a1_sig, positions, window, *, kind="dense",
     if fused:
         # MLP input is independent of this block's attention: add the MHA
         # and MLP partial sums locally, assemble both in ONE all-reduce
-        if is_block0:
-            mlp_in = fal.block0_mlp_input(cfg, p, x, a)
-        else:
-            mlp_in = fal.mlp_input(cfg, p, x, a, a1_sig)
+        mlp_in = _mlp_input(cfg, p, x, a, a1_sig, is_block0)
         y, aux = _ffn_apply(p, cfg, mlp_in, kind, plan)
         return (x + _assemble(a + y, axis, plan.grad_compress),
                 a, aux, new_cache)
@@ -197,17 +212,15 @@ def block_apply(p, cfg, x, a1_sig, positions, window, *, kind="dense",
     resid = x + a
 
     if "xattn" in p:  # whisper decoder cross-attention
-        cx = _assemble(
-            A.gqa_cross_apply(p["xattn"], cfg,
-                              L.norm_apply(p["ln_x"], resid, cfg.norm),
-                              enc_out), axis, plan.grad_compress)
+        with layer_scope("attn"):
+            cx = A.gqa_cross_apply(p["xattn"], cfg,
+                                   L.norm_apply(p["ln_x"], resid, cfg.norm),
+                                   enc_out)
+        cx = _assemble(cx, axis, plan.grad_compress)
         resid = resid + cx
         x = x + cx  # the FAL mlp_input uses x without self-attn but with cross
 
-    if is_block0:
-        mlp_in = fal.block0_mlp_input(cfg, p, x, a)
-    else:
-        mlp_in = fal.mlp_input(cfg, p, x, a, a1_sig)
+    mlp_in = _mlp_input(cfg, p, x, a, a1_sig, is_block0)
 
     y, aux = _ffn_apply(p, cfg, mlp_in, kind, plan)
     y = _assemble(y, axis, plan.grad_compress)
@@ -245,48 +258,30 @@ def _block_apply_dual(p, cfg, x, a1_sig, window, *, kind,
     axis = plan.tp_axis
     # MLP branch input — depends on (x, a1_sig) only; `a=None` is safe
     # because DUAL_BRANCH_MODES never read the block's own attention
-    mlp_in = fal.mlp_input(cfg, p, x, None, a1_sig)
-    h = L.norm_apply(p["ln1"], x, cfg.norm)
+    mlp_in = _mlp_input(cfg, p, x, None, a1_sig, False)
     C = x.shape[1]
     if (plan.phase is Phase.PAGED and kind == "dense" and not cfg.use_mla
             and tok_slot is None and C == 1 and cfg.attn_softcap == 0.0
             and isinstance(window, int) and window == 0):
         # single-token dense tick: fused dual-branch dispatch (padded
-        # layout only — a packed buffer of length 1 is NOT a (B, 1) tick)
-        a, y, new_cache = A.gqa_paged_dual(p["attn"], p["ffn"], cfg, h,
-                                           mlp_in, cache, block_tables,
-                                           pos, n_valid,
-                                           kernels=plan.kernels)
+        # layout only — a packed buffer of length 1 is NOT a (B, 1) tick);
+        # the one kernel runs both branches, so it counts under ``attn``
+        with layer_scope("attn"):
+            h = L.norm_apply(p["ln1"], x, cfg.norm)
+            a, y, new_cache = A.gqa_paged_dual(p["attn"], p["ffn"], cfg, h,
+                                               mlp_in, cache, block_tables,
+                                               pos, n_valid,
+                                               kernels=plan.kernels)
         aux = jnp.zeros((), jnp.float32)
     else:
-        if plan.phase is Phase.PAGED and tok_slot is not None:
-            # token-packed tick: branch-parallel ops (the packed kernel
-            # serves the MHA branch; arithmetic matches the sequential
-            # packed path op-for-op, so tokens stay bit-identical)
-            if cfg.use_mla:
-                a, new_cache = A.mla_packed_apply(p["attn"], cfg, h, cache,
-                                                  block_tables, tok_slot,
-                                                  tok_pos)
-            else:
-                a, new_cache = A.gqa_packed_apply(p["attn"], cfg, h, cache,
-                                                  block_tables, tok_slot,
-                                                  tok_pos, window=window,
-                                                  kernels=plan.kernels)
-        elif plan.phase is Phase.PAGED:
-            if cfg.use_mla:
-                a, new_cache = A.mla_paged_apply(p["attn"], cfg, h, cache,
-                                                 block_tables, pos, n_valid)
-            else:
-                a, new_cache = A.gqa_paged_apply(p["attn"], cfg, h, cache,
-                                                 block_tables, pos, n_valid,
-                                                 window=window,
-                                                 kernels=plan.kernels)
-        else:
-            if cfg.use_mla:
-                a, new_cache = A.mla_decode(p["attn"], cfg, h, cache, pos)
-            else:
-                a, new_cache = A.gqa_decode(p["attn"], cfg, h, cache, pos,
-                                            window=window)
+        # the sequential path's attention ops (the packed kernel serves the
+        # MHA branch of a packed tick; arithmetic matches op-for-op, so
+        # tokens stay bit-identical)
+        a, new_cache = _self_attention(p, cfg, x, None, window, plan,
+                                       cache=cache, pos=pos,
+                                       block_tables=block_tables,
+                                       n_valid=n_valid, tok_slot=tok_slot,
+                                       tok_pos=tok_pos)
         y, aux = _ffn_apply(p, cfg, mlp_in, kind, plan)
     if axis is not None:
         # one fused collective per block, same as the sequential fused path
@@ -337,29 +332,24 @@ def _block_apply_sp(p, cfg, x_s, a1_sig, positions, window, *, kind,
         # plan.grad_compress routes the BACKWARD all-gather (the transpose
         # of this reduce-scatter) through the compressed exchange; 'none'
         # lowers the plain collective, byte-identical to before
-        if plan.grad_compress != "none":
-            return GC.compressed_psum_scatter(v, axis, plan.grad_compress)
-        return jax.lax.psum_scatter(v, axis, scatter_dimension=1, tiled=True)
+        with layer_scope("assemble"):
+            if plan.grad_compress != "none":
+                return GC.compressed_psum_scatter(v, axis,
+                                                  plan.grad_compress)
+            return jax.lax.psum_scatter(v, axis, scatter_dimension=1,
+                                        tiled=True)
 
     def local_slice(full):
         i = jax.lax.axis_index(axis)
         return jax.lax.dynamic_slice_in_dim(full, i * shard, shard, axis=1)
 
     x = gather(x_s)                                    # (B, S, D)
-    h = L.norm_apply(p["ln1"], x, cfg.norm)
-    if cfg.use_mla:
-        a = A.mla_apply(p["attn"], cfg, h, positions, plan=plan)
-    else:
-        a = A.gqa_apply(p["attn"], cfg, h, positions, window=window,
-                        plan=plan)
+    a, _ = _self_attention(p, cfg, x, positions, window, plan)
 
     fused = not (cfg.post_norms
                  or fal.attention_must_assemble(cfg.connection, is_block0))
     if fused:
-        if is_block0:
-            mlp_in = fal.block0_mlp_input(cfg, p, x, a)
-        else:
-            mlp_in = fal.mlp_input(cfg, p, x, a, a1_sig)
+        mlp_in = _mlp_input(cfg, p, x, a, a1_sig, is_block0)
         y, aux = _ffn_apply(p, cfg, mlp_in, kind, plan)
         return x_s + scatter(a + y), a, aux, None
 
@@ -371,17 +361,14 @@ def _block_apply_sp(p, cfg, x_s, a1_sig, positions, window, *, kind,
         if cfg.post_norms:
             a = L.norm_apply(p["post_attn"], a, cfg.norm)
         resid_s = x_s + local_slice(a)
-        mlp_in = fal.block0_mlp_input(cfg, p, x, a)
+        mlp_in = _mlp_input(cfg, p, x, a, None, True)
     else:
         a_s = scatter(a)                               # complete, sharded
         if cfg.post_norms:
             a_s = L.norm_apply(p["post_attn"], a_s, cfg.norm)
         resid_s = x_s + a_s
         sig_s = local_slice(a1_sig) if a1_sig is not None else None
-        if is_block0:
-            mlp_in_s = fal.block0_mlp_input(cfg, p, x_s, a_s)
-        else:
-            mlp_in_s = fal.mlp_input(cfg, p, x_s, a_s, sig_s)
+        mlp_in_s = _mlp_input(cfg, p, x_s, a_s, sig_s, is_block0)
         mlp_in = gather(mlp_in_s)                      # LN region -> full
 
     y, aux = _ffn_apply(p, cfg, mlp_in, kind, plan)

@@ -48,6 +48,7 @@ from repro.models import attention as A
 from repro.models import blocks as BL
 from repro.models import layers as L
 from repro.models import ssm as S
+from repro.obs.trace import layer_scope
 
 
 # ------------------------------------------------------------------------- #
@@ -128,22 +129,25 @@ def constrain_batch(x, plan: Optional[ExecutionPlan]):
 
 
 def _embed_tokens(p, cfg, tokens, positions, image_embeds=None):
-    x = L.embed_apply(p["embed"], tokens, cfg.dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
-    if cfg.learned_pos:
-        x = x + p["pos_emb"].astype(x.dtype)[positions]
-    if image_embeds is not None and cfg.n_image_tokens:
-        n = cfg.n_image_tokens
-        x = jnp.concatenate([image_embeds.astype(x.dtype), x[:, n:]], axis=1)
-    return x
+    with layer_scope("embed"):
+        x = L.embed_apply(p["embed"], tokens, cfg.dtype)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
+        if cfg.learned_pos:
+            x = x + p["pos_emb"].astype(x.dtype)[positions]
+        if image_embeds is not None and cfg.n_image_tokens:
+            n = cfg.n_image_tokens
+            x = jnp.concatenate([image_embeds.astype(x.dtype), x[:, n:]],
+                                axis=1)
+        return x
 
 
 def _logits(p, cfg, x):
-    x = L.norm_apply(p["final_norm"], x, cfg.norm)
-    if cfg.tie_embeddings:
-        return L.unembed_apply(p["embed"], x, cfg.final_softcap)
-    return L.softcap(L.dense_apply(p["head"], x), cfg.final_softcap)
+    with layer_scope("head"):
+        x = L.norm_apply(p["final_norm"], x, cfg.norm)
+        if cfg.tie_embeddings:
+            return L.unembed_apply(p["embed"], x, cfg.final_softcap)
+        return L.softcap(L.dense_apply(p["head"], x), cfg.final_softcap)
 
 
 def decoder_stack_tp(p, cfg, x, positions, plan: ExecutionPlan):
@@ -284,6 +288,13 @@ def _decoder_layer_stack(p, cfg, x, a1_sig, pos, blocks_cache,
     per-layer caches (dense+moe kinds share attention caches; the ffn kind
     switch is static per segment).  Returns (x, new_stacked_cache).
 
+    The scan moves each layer's page pools out of the stacked cache and
+    back, so it runs under the ``kv_write`` scope: what the layers compute
+    carries its own (inner) scopes, and the pool slices and updates the
+    scan itself adds count as K/V writes.  So do the slices of the
+    layers' weights, and any op of the body no inner scope claims (the
+    residual adds, where XLA leaves them unfused).
+
     When every layer's window is statically 0 (no sliding windows) the
     window rides into the scan body as a Python int instead of a traced
     vector — attention's static ``window == 0`` checks then hold, keeping
@@ -325,12 +336,15 @@ def _decoder_layer_stack(p, cfg, x, a1_sig, pos, blocks_cache,
 
             xs = (pseg, cache_seg) if static_zero else \
                 (pseg, ws, cache_seg)
-            x, cseg = jax.lax.scan(body, x, xs)
+            with layer_scope("kv_write"):
+                x, cseg = jax.lax.scan(body, x, xs)
             seg_caches.append(cseg)
             i += n
     if not seg_caches:                         # limit == 0: block 0 only
         return x, None
-    return x, jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *seg_caches)
+    with layer_scope("kv_write"):
+        return x, jax.tree.map(lambda *xs: jnp.concatenate(xs, 0),
+                               *seg_caches)
 
 
 def _decoder_decode(p, cfg, batch, cache, plan: ExecutionPlan):
@@ -509,9 +523,11 @@ def _decoder_paged_packed(p, cfg, batch, cache, plan: ExecutionPlan,
     # packed token; slots sitting this tick out keep their cached signal
     sig = a1_sig if a1_sig is not None else a1_raw              # (1, T, D)
     active = seg_last >= 0
-    new_sig = sig[0, jnp.maximum(seg_last, 0)].astype(cache["a1_sig"].dtype)
-    new_caches["a1_sig"] = jnp.where(active[:, None], new_sig,
-                                     cache["a1_sig"])
+    with layer_scope("fal_signal"):
+        new_sig = sig[0, jnp.maximum(seg_last, 0)].astype(
+            cache["a1_sig"].dtype)
+        new_caches["a1_sig"] = jnp.where(active[:, None], new_sig,
+                                         cache["a1_sig"])
 
     x, blocks_new = _decoder_layer_stack(p, cfg, x, a1_sig, None,
                                          cache["blocks"], plan,
@@ -1042,12 +1058,13 @@ def lm_head_segment_tail(params, cfg, hidden, seg_last, n):
     NaN/garbage in scratch rows can never reach a sampled token —
     callers mask which columns are live (a non-speculative segment's
     only live column is the last)."""
-    off = jnp.arange(n, dtype=jnp.int32) - (n - 1)               # (n,)
-    rows = seg_last[:, None] + off[None, :]                      # (S, n)
-    valid = (seg_last >= 0)[:, None] & (rows >= 0)
-    h = hidden[0, jnp.maximum(rows, 0)]                          # (S, n, D)
-    h = jnp.where(valid[:, :, None], h, 0.0)
-    return _logits(params, cfg, h), rows
+    with layer_scope("head"):
+        off = jnp.arange(n, dtype=jnp.int32) - (n - 1)           # (n,)
+        rows = seg_last[:, None] + off[None, :]                  # (S, n)
+        valid = (seg_last >= 0)[:, None] & (rows >= 0)
+        h = hidden[0, jnp.maximum(rows, 0)]                      # (S, n, D)
+        h = jnp.where(valid[:, :, None], h, 0.0)
+        return _logits(params, cfg, h), rows
 
 
 def copy_paged_pages(cache, src, dst, *, kernels="auto"):
@@ -1102,8 +1119,9 @@ def _mtp_loss(p, cfg, batch, hidden):
 
 
 def _ce_tail(p, cfg, hidden, tokens):
-    logits = _logits(p, cfg, hidden)
-    return cross_entropy(logits[:, :-1], tokens[:, 1:])
+    with layer_scope("head"):
+        logits = _logits(p, cfg, hidden)
+        return cross_entropy(logits[:, :-1], tokens[:, 1:])
 
 
 def loss_fn(params, cfg, batch, plan=None):

@@ -83,6 +83,46 @@ train_<metric>                          gauge      —        train/trainer.py  
 train_eval_ppl                          gauge      ppl      train/trainer.py    eval cadence
 ======================================  =========  =======  ==========================================
 
+Layer-scope reference
+=====================
+
+``obs.trace.LAYER_SCOPES`` names the layers of the train step and the
+packed serving tick; each is a ``jax.named_scope`` (``obs.trace.layer_scope``)
+and so lives only in the compiled program's metadata: every HLO op carries
+its name stack (``op_name``), and a device trace carries it per op as the
+``tf_op`` stat.  A reader takes the innermost scope of the table in the
+stack (``kv_write`` sits inside ``attn``); backward ops read
+``transpose(jvp(...))`` around the same scopes.  XLA gives a fusion the
+metadata of its root op, so a fusion is counted under its root's scope.
+
+==========  =================================================================
+scope       opened around
+==========  =================================================================
+embed       models/model.py  _embed_tokens (token and position embeddings)
+attn        models/blocks.py  ln1 + the attention call, every phase and variant
+kv_write    models/attention.py  paged_scatter / packed_scatter (K, V, scales);
+            models/model.py  the paged layer scan (_decoder_layer_stack):
+            its slices of each layer's page pools and weights, the pools'
+            layout copies and write-back, and any op of the layer body
+            that no inner scope claims (the residual adds, where XLA
+            leaves them unfused)
+fal_signal  models/blocks.py  fal.mlp_input / fal.block0_mlp_input
+mlp         models/blocks.py  _ffn_apply
+assemble    models/blocks.py  _assemble (TP all-reduce), the SP reduce-scatter
+head        models/model.py  _logits, _ce_tail, lm_head_segment_tail
+sample      serve/scheduler.py  make_packed_step's fused sampler
+optimizer   train/step.py  adamw_update (clip, global norm, moments)
+==========  =================================================================
+
+The engine's host spans (``PagedEngine``, ``annotate=True``, so they sit on
+the profiler's clock beside the device ops): ``engine.tick`` around
+``engine.admit`` (``_admit``), ``engine.plan`` (``_plan_pack`` and
+``_ensure``, preemption and ``engine.cow`` included), ``engine.upload``
+(the tick's host arrays to the device), ``engine.dispatch`` (the jitted
+call), ``engine.sync`` (the wait for the sampled tokens) and
+``engine.consume``.  ``engine_dispatch_ms`` times a tick's dispatch
+through its sync.
+
 ``kernel_dispatch_total`` counts TRACES, not executed calls: the
 dispatchers run under ``jax.jit``, so the per-site record fires when a
 (site, shape) program is traced and the chosen path cannot change without

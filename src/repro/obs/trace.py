@@ -16,11 +16,19 @@ Design constraints (the serving hot loop calls this every tick):
 
 ``span(..., annotate=True)`` additionally enters
 ``jax.profiler.TraceAnnotation`` so spans emitted around jitted dispatches
-line up with XLA device traces when ``jax.profiler`` captures are taken.
+line up with XLA device traces when ``jax.profiler`` captures are taken;
+the export's ``otherData`` carries the epoch on both ``perf_counter`` and
+the wall clock the profiler stamps its events with, so the two traces can
+be laid over each other.
+
+``LAYER_SCOPES`` is the fixed table of layer names the programs open with
+``layer_scope`` (a ``jax.named_scope``): they exist only in the traced
+program's metadata and cost nothing at run time.
 """
 from __future__ import annotations
 
 import json
+import re
 import time
 from typing import Any, Dict, List
 
@@ -37,6 +45,34 @@ class _NullContext:
 
 
 _NULL_CTX = _NullContext()
+
+#: layer scopes of the train step and the packed serving tick; each op of a
+#: compiled program carries its name stack, and a trace reader takes the
+#: innermost of these names in it (the ``obs`` package docstring maps each
+#: to the code it covers)
+LAYER_SCOPES = ("embed", "attn", "kv_write", "fal_signal", "mlp",
+                "assemble", "head", "sample", "optimizer")
+
+
+_STACK_PARTS = re.compile(r"[/():]")
+
+
+def innermost_scope(stack: str):
+    """The innermost ``LAYER_SCOPES`` name in an op's name stack (its HLO
+    ``op_name``, a device trace's ``tf_op``), or None outside every layer.
+    Transforms wrap the stack (``transpose(jvp(...))``) and are skipped."""
+    for part in reversed(_STACK_PARTS.split(stack)):
+        if part in LAYER_SCOPES:
+            return part
+    return None
+
+
+def layer_scope(name: str):
+    """``jax.named_scope`` for one name of ``LAYER_SCOPES``."""
+    if name not in LAYER_SCOPES:
+        raise ValueError(f"{name!r} is not a layer scope {LAYER_SCOPES}")
+    import jax
+    return jax.named_scope(name)
 
 
 class _Span:
@@ -82,8 +118,12 @@ class Tracer:
         self.enabled = enabled
         self.pid = pid
         self.process_name = process_name
-        self.epoch_ns = time.perf_counter_ns()
         self._events: List[Dict[str, Any]] = []
+        self._set_epoch()
+
+    def _set_epoch(self):
+        self.epoch_ns = time.perf_counter_ns()
+        self.epoch_wall_ns = time.time_ns()
 
     # ------------------------------------------------------------- emit ----
     def _ts(self) -> float:
@@ -141,14 +181,16 @@ class Tracer:
 
     def clear(self):
         self._events.clear()
-        self.epoch_ns = time.perf_counter_ns()
+        self._set_epoch()
 
     def export(self) -> dict:
         """Chrome trace-event JSON object (Perfetto-loadable)."""
         meta = [{"name": "process_name", "ph": "M", "pid": self.pid, "tid": 0,
                  "ts": 0, "args": {"name": self.process_name}}]
         return {"traceEvents": meta + self._events,
-                "displayTimeUnit": "ms"}
+                "displayTimeUnit": "ms",
+                "otherData": {"epoch_perf_counter_ns": self.epoch_ns,
+                              "epoch_wall_ns": self.epoch_wall_ns}}
 
     def write(self, path: str) -> str:
         with open(path, "w") as f:

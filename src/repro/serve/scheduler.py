@@ -93,7 +93,7 @@ import numpy as np
 from repro.core.plan import ExecutionPlan, Phase
 from repro.models import model as M
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import NULL_TRACER, layer_scope
 from repro.serve import sampling as SP
 from repro.serve.paged_cache import BlockTable, PageAllocator, pages_needed
 from repro.serve.prefix_cache import PrefixCache
@@ -146,12 +146,15 @@ def make_packed_step(cfg, plan=None, *, sampler=None):
         # garbage there must never reach a sampled token) and return the
         # -1 sentinel instead of a sampled id
         active = seg_last >= 0
-        h_seg = jnp.where(active[:, None],
-                          hidden[0, jnp.maximum(seg_last, 0)], 0.0)  # (S, D)
-        logits = M.lm_head(params, cfg, h_seg[:, None])[:, 0]    # (S, V)
-        nxt = jax.vmap(samp)(logits, temps, top_ks, top_ps,
-                             seeds, sample_pos)
-        nxt = jnp.where(active, nxt, jnp.int32(-1))
+        with layer_scope("head"):
+            h_seg = jnp.where(active[:, None],
+                              hidden[0, jnp.maximum(seg_last, 0)],
+                              0.0)                                 # (S, D)
+            logits = M.lm_head(params, cfg, h_seg[:, None])[:, 0]  # (S, V)
+        with layer_scope("sample"):
+            nxt = jax.vmap(samp)(logits, temps, top_ks, top_ps,
+                                 seeds, sample_pos)
+            nxt = jnp.where(active, nxt, jnp.int32(-1))
         return logits, nxt, new_cache
 
     return jax.jit(step, donate_argnums=(1,))
@@ -230,8 +233,9 @@ def make_spec_step(cfg, plan=None, *, spec_tokens, draft_blocks,
                                            plan, draft_blocks=draft_blocks)
             h = jnp.where(spec_mask[:, None], dh[0], 0.0)        # (S, D)
             dlogits = M.lm_head(params, cfg, h[:, None])[:, 0]   # (S, V)
-            dnext = jax.vmap(samp)(dlogits, temps, top_ks,
-                                   top_ps, seeds, dpos + 1)
+            with layer_scope("sample"):
+                dnext = jax.vmap(samp)(dlogits, temps, top_ks,
+                                       top_ps, seeds, dpos + 1)
             wrow = jnp.where(spec_mask, seg_start + j + 1, T)
             toks = toks.at[wrow].set(dnext, mode="drop")
         # ---- verify: the tick's ONE full-depth packed dispatch --------
@@ -246,8 +250,10 @@ def make_spec_step(cfg, plan=None, *, spec_tokens, draft_blocks,
                 & (spec_mask[:, None] | (col == n - 1)))
         rpos = tok_pos[jnp.maximum(rows, 0)]                     # (S, n)
         one = jax.vmap(samp, in_axes=(0, None, None, None, None, 0))
-        tgt = jax.vmap(one)(logits, temps, top_ks, top_ps, seeds, rpos + 1)
-        tgt = jnp.where(live, tgt, jnp.int32(-1))
+        with layer_scope("sample"):
+            tgt = jax.vmap(one)(logits, temps, top_ks, top_ps, seeds,
+                                rpos + 1)
+            tgt = jnp.where(live, tgt, jnp.int32(-1))
         fed = jnp.where(live, toks[jnp.maximum(rows, 0)], jnp.int32(-1))
         return tgt, fed, new_cache
 
@@ -937,8 +943,12 @@ class PagedEngine:
         completed this call.  Lanes may be in DIFFERENT phases: lane i
         advances its ``pt.n_taken[i]`` packed tokens (under speculation a
         decode lane's segment spans its whole n-token proposal and may
-        emit up to n tokens)."""
+        emit up to n tokens).  The plain and the speculative tick share
+        one path through the ``engine.upload``, ``engine.dispatch``,
+        ``engine.sync`` and ``engine.consume`` spans; the plain tick waits
+        for the device only when some lane completes its context."""
         S = self.ecfg.slots
+        tr = self.tracer
         ids = [i for i in range(S) if pt.n_taken[i] > 0]
         self.dispatches += 1
         self._c_dispatches.inc()
@@ -946,49 +956,60 @@ class PagedEngine:
         T = pt.tokens.shape[0]
         self._h_tok_disp.record(pt.n_live)
         self._h_pad_frac.record(1.0 - pt.n_live / T)
-        bt = np.stack([t.as_row() for t in self.tables])
-        temps = np.zeros((S,), np.float32)
-        ks = np.zeros((S,), np.int32)
-        ps = np.ones((S,), np.float32)
-        seeds = np.zeros((S,), np.int32)
-        poss = np.zeros((S,), np.int32)
-        spec_mask = np.zeros((S,), bool)
-        for i in ids:
-            r = self.slots[i]
-            sp = r.sampling
-            temps[i], ks[i], ps[i] = sp.temperature, sp.top_k, sp.top_p
-            seeds[i] = sp.seed
-            # position of the would-be new token (== len(known()) exactly
-            # when this call completes the request's context)
-            poss[i] = r.pos + int(pt.n_taken[i])
-            # a decode lane whose segment spans > 1 row is speculating
-            # (only _plan_pack's spec-eligible lanes pack that way)
-            spec_mask[i] = (len(r.known()) - r.pos == 1
-                            and int(pt.n_taken[i]) > 1)
-        step_fn = self._step_for(all(
-            SP.fast_eligible(self.slots[i].sampling, self.cfg.vocab)
-            for i in ids))
+        with tr.span("engine.upload", annotate=True):
+            bt = np.stack([t.as_row() for t in self.tables])
+            temps = np.zeros((S,), np.float32)
+            ks = np.zeros((S,), np.int32)
+            ps = np.ones((S,), np.float32)
+            seeds = np.zeros((S,), np.int32)
+            poss = np.zeros((S,), np.int32)
+            spec_mask = np.zeros((S,), bool)
+            for i in ids:
+                r = self.slots[i]
+                sp = r.sampling
+                temps[i], ks[i], ps[i] = sp.temperature, sp.top_k, sp.top_p
+                seeds[i] = sp.seed
+                # position of the would-be new token (== len(known())
+                # exactly when this call completes the request's context)
+                poss[i] = r.pos + int(pt.n_taken[i])
+                # a decode lane whose segment spans > 1 row is speculating
+                # (only _plan_pack's spec-eligible lanes pack that way)
+                spec_mask[i] = (len(r.known()) - r.pos == 1
+                                and int(pt.n_taken[i]) > 1)
+            step_fn = self._step_for(all(
+                SP.fast_eligible(self.slots[i].sampling, self.cfg.vocab)
+                for i in ids))
+            # the speculative program takes the mask where the plain one
+            # takes the sample positions (it samples at each row's own)
+            tail = (spec_mask, temps, ks, ps, seeds) if self.spec \
+                else (temps, ks, ps, seeds, poss)
+            args = [jnp.asarray(a) for a in
+                    (pt.tokens, pt.tok_slot, pt.tok_pos, bt, pt.seg_last)
+                    + tail]
         t0 = time.perf_counter()
-        if self.spec:
-            with self.tracer.span("engine.dispatch", annotate=True,
-                                  lanes=len(ids), live_tokens=pt.n_live,
-                                  budget=T, spec_lanes=int(spec_mask.sum())):
-                tgt, fed, self.cache = step_fn(
-                    self.params, self.cache, jnp.asarray(pt.tokens),
-                    jnp.asarray(pt.tok_slot), jnp.asarray(pt.tok_pos),
-                    jnp.asarray(bt), jnp.asarray(pt.seg_last),
-                    jnp.asarray(spec_mask), jnp.asarray(temps),
-                    jnp.asarray(ks), jnp.asarray(ps), jnp.asarray(seeds))
-            self._h_dispatch_ms.record((time.perf_counter() - t0) * 1e3)
-            tgt_np, fed_np = np.asarray(tgt), np.asarray(fed)
-            now = time.perf_counter()
+        with tr.span("engine.dispatch", annotate=True, lanes=len(ids),
+                     live_tokens=pt.n_live, budget=T,
+                     spec_lanes=int(spec_mask.sum())):
+            # plain: (seg_logits, next tokens); speculative: (targets,
+            # fed tokens)
+            out_a, out_b, self.cache = step_fn(self.params, self.cache,
+                                               *args)
+        # does this call complete some lane's context (a token to read)?
+        done = any(self.slots[i].pos + int(pt.n_taken[i])
+                   == len(self.slots[i].known()) for i in ids)
+        with tr.span("engine.sync", annotate=True):
+            got = ((np.asarray(out_a), np.asarray(out_b)) if self.spec
+                   else (np.asarray(out_b),) if done else ())
+        self._h_dispatch_ms.record((time.perf_counter() - t0) * 1e3)
+        now = time.perf_counter()
+        with tr.span("engine.consume", annotate=True):
             for i in ids:
                 r = self.slots[i]
                 adv = int(pt.n_taken[i])
                 if spec_mask[i]:
                     # pos/decode-token accounting live inside the helper:
                     # only the ACCEPTED prefix advances the lane
-                    self._consume_spec_lane(i, tgt_np[i], fed_np[i], now)
+                    self._consume_spec_lane(i, got[0][i], got[1][i], now)
                     continue
                 if len(r.known()) - r.pos == 1:
                     self._c_decode_toks.inc(adv)
@@ -996,35 +1017,11 @@ class PagedEngine:
                     self._c_prefill_toks.inc(adv)
                 r.pos += adv
                 if r.pos == len(r.known()):
-                    # non-spec lane: the verify pass's last column is the
-                    # plain packed-tick sample at position pos
-                    self._consume_one(i, int(tgt_np[i][self.spec - 1]), now)
-            return
-        with self.tracer.span("engine.dispatch", annotate=True,
-                              lanes=len(ids), live_tokens=pt.n_live,
-                              budget=T):
-            _, nxt, self.cache = step_fn(
-                self.params, self.cache, jnp.asarray(pt.tokens),
-                jnp.asarray(pt.tok_slot), jnp.asarray(pt.tok_pos),
-                jnp.asarray(bt), jnp.asarray(pt.seg_last),
-                jnp.asarray(temps), jnp.asarray(ks), jnp.asarray(ps),
-                jnp.asarray(seeds), jnp.asarray(poss))
-        self._h_dispatch_ms.record((time.perf_counter() - t0) * 1e3)
-        for i in ids:
-            r = self.slots[i]
-            adv = int(pt.n_taken[i])
-            if len(r.known()) - r.pos == 1:
-                self._c_decode_toks.inc(adv)
-            else:
-                self._c_prefill_toks.inc(adv)
-            r.pos += adv
-        need = [i for i in ids
-                if self.slots[i].pos == len(self.slots[i].known())]
-        if need:
-            nxt_np = np.asarray(nxt)
-            now = time.perf_counter()
-            for i in need:
-                self._consume_one(i, int(nxt_np[i]), now)
+                    # the plain sample, or the speculative verify pass's
+                    # last column (the plain packed-tick sample at pos)
+                    tok = got[0][i][self.spec - 1] if self.spec \
+                        else got[0][i]
+                    self._consume_one(i, int(tok), now)
 
     # ------------------------------------------------------------------ #
     def step(self):
@@ -1032,8 +1029,10 @@ class PagedEngine:
         active lane at its own phase."""
         self.ticks += 1
         self._c_ticks.inc()
-        with self.tracer.span("engine.tick", tick=self.ticks):
-            self._admit()
+        with self.tracer.span("engine.tick", annotate=True,
+                              tick=self.ticks):
+            with self.tracer.span("engine.admit", annotate=True):
+                self._admit()
             d0 = self.dispatches
             self._step_packed()
             if self.dispatches > d0:
@@ -1049,10 +1048,20 @@ class PagedEngine:
         is re-planned until the surviving lanes' plan sticks (each
         non-final iteration empties at least one slot, bounding the loop
         at slots + 1)."""
+        with self.tracer.span("engine.plan", annotate=True):
+            pt = self._plan_packed()
+        if pt is not None:
+            self.packed_calls += 1
+            self._c_packed.inc()
+            self._run_packed(pt)
+
+    def _plan_packed(self) -> Optional[PackedTick]:
+        """The tick's pack once every packed lane's pages are ensured, or
+        None when no lane has a token to feed."""
         for _ in range(self.ecfg.slots + 1):
             pt = self._plan_pack()
             if pt.n_live == 0:
-                return
+                return None
             replan = False
             for i in range(self.ecfg.slots):
                 if pt.n_taken[i] == 0 or self.slots[i] is None:
@@ -1065,10 +1074,8 @@ class PagedEngine:
             if not replan and all(
                     self.slots[i] is not None
                     for i in range(self.ecfg.slots) if pt.n_taken[i] > 0):
-                self.packed_calls += 1
-                self._c_packed.inc()
-                self._run_packed(pt)
-                return
+                return pt
+        return None
 
     def run(self, max_ticks: Optional[int] = None) -> List[ServeRequest]:
         while any(s is not None for s in self.slots) or self.queue:

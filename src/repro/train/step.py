@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.core.plan import ExecutionPlan, Phase
 from repro.models import model as M
+from repro.obs.trace import layer_scope
 from repro.optim import adamw
 
 
@@ -75,8 +76,9 @@ def make_train_step(cfg, ocfg: adamw.AdamWConfig, plan=None,
             l = l_sum / num_microbatches
             metrics = {}
 
-        new_params, new_opt, gnorm = adamw.adamw_update(
-            params, grads, state["opt"], ocfg)
+        with layer_scope("optimizer"):
+            new_params, new_opt, gnorm = adamw.adamw_update(
+                params, grads, state["opt"], ocfg)
         metrics = dict(metrics, loss=l, grad_norm=gnorm)
         return {"params": new_params, "opt": new_opt}, metrics
 

@@ -178,6 +178,38 @@ def test_tracer_clear_resets_epoch():
     validate_chrome_trace(tr.export())
 
 
+def test_tracer_export_lays_over_a_profiler_trace(tmp_path):
+    """The export's epoch on the wall clock the profiler stamps its events
+    with: an annotated span lands where the device trace has it."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+    before = time.time_ns()
+    tr = Tracer(enabled=True)
+    assert before <= tr.epoch_wall_ns <= time.time_ns()
+    jax.profiler.start_trace(str(tmp_path))
+    with tr.span("engine.probe", annotate=True):
+        time.sleep(0.01)
+    jax.profiler.stop_trace()
+    obj = tr.export()
+    validate_chrome_trace(obj)
+    assert obj["otherData"] == {"epoch_perf_counter_ns": tr.epoch_ns,
+                                "epoch_wall_ns": tr.epoch_wall_ns}
+    ev = next(e for e in obj["traceEvents"] if e["name"] == "engine.probe")
+    pd = ProfileData.from_file(glob.glob(
+        str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0])
+    start = dict(next(p for p in pd.planes
+                      if p.name == "Task Environment").stats)[
+        "profile_start_time"]
+    xev = next(e for p in pd.planes for line in p.lines for e in line.events
+               if e.name == "engine.probe")
+    at = tr.epoch_wall_ns + ev["ts"] * 1e3 - start    # on the trace's clock
+    assert abs(at - xev.start_ns) < 1e6
+    assert abs(ev["dur"] * 1e3 - xev.duration_ns) < 1e6
+
+
 def test_validate_chrome_trace_rejects_malformed():
     ok = {"name": "a", "ph": "i", "pid": 0, "tid": 0, "ts": 1.0, "s": "t"}
     validate_chrome_trace({"traceEvents": [ok]})
@@ -198,7 +230,7 @@ def test_validate_chrome_trace_rejects_malformed():
 # --------------------------------------------------------------------------- #
 # engine lifecycle + dispatch telemetry (slow half: real engine runs)
 # --------------------------------------------------------------------------- #
-def _engine(tracer=None, num_pages=48, slots=4):
+def _engine(tracer=None, num_pages=48, slots=4, **ecfg):
     import jax
     from repro.configs.base import get_config
     from repro.models import model as M
@@ -207,7 +239,7 @@ def _engine(tracer=None, num_pages=48, slots=4):
     params = M.init_params(jax.random.PRNGKey(0), cfg)
     return cfg, PagedEngine(cfg, params, EngineConfig(
         page_size=8, num_pages=num_pages, slots=slots, prefill_chunk=8,
-        max_seq=64), tracer=tracer)
+        max_seq=64, **ecfg), tracer=tracer)
 
 
 def _events(tracer, rid):
@@ -270,6 +302,67 @@ def test_engine_stats_reset_zeroes_registry_and_trace():
         assert getattr(s, "count", getattr(s, "value", 0)) in (0, 0.0), name
     st = eng.stats()
     assert st["ticks"] == 0 and st["ttft_ms"]["count"] == 0
+
+
+TICK_PHASES = ("engine.admit", "engine.plan", "engine.upload",
+               "engine.dispatch", "engine.sync", "engine.consume")
+
+
+@pytest.mark.parametrize("spec", [0, 4], ids=["plain", "speculative"])
+def test_engine_tick_phases_nest_in_each_tick(spec):
+    """Each tick's host phases, in order and inside its ``engine.tick``
+    span, on the plain and the speculative path alike."""
+    from repro.serve.scheduler import ServeRequest
+    tracer = Tracer(enabled=True)
+    extra = dict(spec_tokens=spec, draft_blocks=1) if spec else {}
+    cfg, eng = _engine(tracer=tracer, **extra)
+    for i in range(3):
+        eng.submit(ServeRequest(rid=i, prompt=np.arange(5 + 4 * i) % cfg.vocab,
+                                max_new=5))
+    eng.run()
+    xs = [e for e in tracer.events if e["ph"] == "X"]
+    ticks = [e for e in xs if e["name"] == "engine.tick"]
+    assert len(ticks) == eng.ticks
+    dispatched = 0
+    for t in ticks:
+        inside = [e for e in xs if e["name"] in TICK_PHASES
+                  and t["ts"] <= e["ts"]
+                  and e["ts"] + e["dur"] <= t["ts"] + t["dur"] + 1e-3]
+        names = [e["name"] for e in sorted(inside, key=lambda e: e["ts"])]
+        assert names in (list(TICK_PHASES[:2]), list(TICK_PHASES)), names
+        dispatched += names == list(TICK_PHASES)
+    assert dispatched == eng.dispatches > 0
+    assert sum(e["name"] == "engine.dispatch" for e in xs) == eng.dispatches
+    # the dispatch histogram closes after the sync: one sample a dispatch
+    assert eng.stats()["dispatch_ms"]["count"] == eng.dispatches
+
+
+def test_engine_null_tracer_costs_no_annotation(monkeypatch):
+    """The default tracer records nothing and enters no
+    ``TraceAnnotation``; an enabled one annotates every phase."""
+    import jax
+    from repro.serve.scheduler import ServeRequest
+    made = []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    for tracer in (None, Tracer(enabled=True)):
+        cfg, eng = _engine(tracer=tracer)
+        eng.submit(ServeRequest(rid=0, prompt=np.arange(6) % cfg.vocab,
+                                max_new=3))
+        eng.run()
+        if tracer is None:
+            assert made == [] and NULL_TRACER.events == []
+    assert set(TICK_PHASES) | {"engine.tick"} <= set(made)
 
 
 def test_kernel_dispatch_paths_runtime_measured():

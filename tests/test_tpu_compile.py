@@ -1,23 +1,27 @@
 """Compile the main-path Pallas kernels for a described TPU v5e chip at
-``gpt2-117m`` width.  Nothing runs: the TPU compiler, which is installed
-with JAX, compiles for a topology that is described and not attached, and
-refuses what the chip would refuse — blocks that break the (8, 128) tiling
-rule, too much VMEM — which interpret mode cannot catch.
+``gpt2-117m`` width, and the train step and the packed serving tick at
+reduced widths to check the layer scopes their ops carry.  Nothing runs:
+the TPU compiler, which is installed with JAX, compiles for a topology
+that is described and not attached, and refuses what the chip would
+refuse — blocks that break the (8, 128) tiling rule, too much VMEM —
+which interpret mode cannot catch.
 
 The topology is described inside a module-scope fixture, never at import
 time: only one process may load the TPU library, and test collection runs
 in every worker.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from repro.configs.base import get_config
 from repro.kernels import ops
 from repro.models.layers import mlp_init
+from repro.obs.trace import innermost_scope
 
 CFG = get_config("gpt2-117m")
 H, HKV, D = CFG.n_heads, CFG.n_kv_heads, CFG.resolved_head_dim
@@ -134,3 +138,111 @@ def test_dual_branch_dispatch_compiles(one_chip, max_blocks, site):
         (ffn["wi"].shape, jnp.bfloat16), (ffn["wo"].shape, jnp.bfloat16))
     assert "tpu_custom_call" in txt
     assert ops.dispatch_paths() == {site: "fused-tpu"}
+
+
+# --------------------------------------------------------------------------- #
+# layer scopes in the compiled programs (obs.trace.LAYER_SCOPES)
+# --------------------------------------------------------------------------- #
+SCOPED = ("dot", "convolution", "custom-call")
+_OPCODE = re.compile(r"= \S+ ([\w-]+)\(")
+_STACK = re.compile(r'op_name="([^"]*)"')
+FAL = get_config("gpt2-774m").reduced().replace(connection="fal")
+
+
+def _ops(txt, kinds):
+    """(opcode, name stack) of each instruction of compiled HLO text whose
+    opcode starts with one of ``kinds``."""
+    out = []
+    for line in txt.splitlines():
+        m = _OPCODE.search(line)
+        if m and m.group(1).startswith(kinds):
+            st = _STACK.search(line)
+            out.append((m.group(1), st.group(1) if st else ""))
+    return out
+
+
+def _assert_each_op_has_a_layer(txt):
+    ops_ = _ops(txt, SCOPED)
+    assert ops_
+    bare = [o for o in ops_ if innermost_scope(o[1]) is None]
+    assert not bare, bare[:5]
+    return ops_
+
+
+def _train_step_text(topo, n_model):
+    from repro.core.plan import ExecutionPlan
+    from repro.launch import mesh as MX
+    from repro.optim import adamw
+    from repro.train import step as tstep
+    ocfg = adamw.AdamWConfig()
+    mesh = MX.make_mesh((1, n_model), ("data", "model"),
+                        devices=topo.devices[:n_model])
+    plan = (ExecutionPlan.from_mesh(mesh, tp="explicit") if n_model > 1
+            else ExecutionPlan.single_device())
+    shapes = jax.eval_shape(lambda k: tstep.init_state(k, FAL, ocfg),
+                            jax.random.PRNGKey(0))
+    st_sh = MX.shardings_for(mesh, MX.state_specs(shapes, FAL))
+    state = jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sh), shapes, st_sh)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (4, 32), jnp.int32,
+        sharding=NamedSharding(mesh, MX.batch_spec(mesh)))}
+    return jax.jit(tstep.make_train_step(FAL, ocfg, plan)).lower(
+        state, batch).compile().as_text()
+
+
+def test_train_step_ops_carry_one_layer_scope(one_chip, topo):
+    ops_ = _assert_each_op_has_a_layer(_train_step_text(topo, 1))
+    scopes = {innermost_scope(s) for _, s in ops_}
+    assert {"attn", "mlp", "head", "embed"} <= scopes
+    # backward ops keep the forward's scopes inside transpose(jvp(...))
+    assert any("transpose(" in s and innermost_scope(s) == "attn"
+               for _, s in ops_)
+
+
+def test_tp_train_step_ops_and_all_reduces_carry_layer_scopes(one_chip,
+                                                             topo):
+    """Explicit TP over the described 2x2: every matmul has a layer, and
+    the all-reduces ``blocks._assemble`` emits (block 0's two, one per
+    later block under FAL) and their transposes carry ``assemble``."""
+    txt = _train_step_text(topo, 4)
+    _assert_each_op_has_a_layer(txt)
+    asm = [s for _, s in _ops(txt, ("all-reduce",))
+           if innermost_scope(s) == "assemble"]
+    fwd = [s for s in asm if "transpose(" not in s]
+    bwd = [s for s in asm if "transpose(" in s]
+    assert len(fwd) == len(bwd) == FAL.n_layers + 1, asm
+
+
+def test_packed_tick_ops_carry_one_layer_scope(one_chip):
+    """The serving tick with the Pallas kernels: the paged attention
+    kernel under ``attn``, the page writes under ``kv_write``, the
+    sampler under ``sample``.  The paged layer scan runs under
+    ``kv_write`` too (its pool slices and updates), so no matmul or
+    kernel of a layer's body may fall through to the scan's scope."""
+    from repro.core.plan import ExecutionPlan
+    from repro.models import model as M
+    from repro.serve.scheduler import make_packed_step
+    S, T, page, pages, Tb = 4, 19, 16, 32, 8
+    sd = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    params = jax.eval_shape(lambda k: M.init_params(k, FAL),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: M.init_paged_cache(FAL, pages, page, S,
+                                                      "bfloat16"))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    step = make_packed_step(
+        FAL, ExecutionPlan.single_device().with_kernels("pallas"))
+    txt = step.lower(jax.tree.map(sd, params), jax.tree.map(sd, cache),
+                     i32(T), i32(T), i32(T), i32(S, Tb), i32(S), f32(S),
+                     i32(S), f32(S), i32(S), i32(S)).compile().as_text()
+    ops_ = _assert_each_op_has_a_layer(txt)
+    kernel = [s for op, s in ops_ if op == "custom-call"
+              and "paged_packed_attention" in s]
+    assert kernel and {innermost_scope(s) for s in kernel} == {"attn"}
+    leaked = [o for o in ops_ if innermost_scope(o[1]) == "kv_write"
+              and "attn/kv_write" not in o[1]]
+    assert not leaked, leaked[:5]
+    every = {innermost_scope(s) for line in txt.splitlines()
+             for s in _STACK.findall(line)}
+    assert {"kv_write", "sample", "head", "embed"} <= every
