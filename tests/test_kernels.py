@@ -137,32 +137,79 @@ def test_paged_chunk_attention_c1_matches_decode_kernel():
     assert jnp.max(jnp.abs(chunk[:, 0] - dec)) < 2e-5
 
 
-@pytest.mark.parametrize("budget", [8, 16])
-def test_paged_packed_attention_sweep(budget):
-    """Packed ragged kernel vs its gather oracle: one flat token buffer
-    holding a prefill segment that straddles a page boundary, a mid-page
-    decode segment, and a padding tail (tok_pos == -1)."""
-    S, H, Hkv, D, page, T = 3, 8, 2, 32, 8, 6
-    P = T * S + 2
-    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+#: packed-kernel sweep cases: (H, Hkv, D, page, Tb, budget, kv storage,
+#: segments as (slot, first position, tokens) in buffer order, and the
+#: kernel's VMEM budgets and query windows patched smaller so that small
+#: shapes take several KV blocks, head groups or query windows)
+_PACKED_CASES = {
+    # slot 0: 6-token prefill crossing the first page boundary; slot 1: a
+    # decode token mid-page; slot 2 sits out; a padding tail
+    "8": (8, 2, 32, 8, 6, 8, None, [(0, 5, 6), (1, 21, 1)], {}),
+    "16": (8, 2, 32, 8, 6, 16, None, [(0, 5, 6), (1, 21, 1)], {}),
+    # decode only: one token per slot, contexts of 1 to 6 pages, one
+    # ending on a page boundary
+    "decode": (8, 2, 32, 8, 6, 12, None,
+               [(0, 0, 1), (1, 7, 1), (2, 8, 1), (3, 30, 1), (4, 47, 1)],
+               {}),
+    # decode tokens and a 30-token prefill whose context ends mid-page and
+    # spans 5 pages, in KV blocks of 2 pages and query windows of 8/16
+    "prefill_blocks": (8, 2, 32, 8, 6, 40, None,
+                       [(0, 12, 1), (1, 3, 1), (2, 9, 30), (3, 40, 1)],
+                       {"_KV_BLOCK_VMEM": 2 * 2 * 2 * (2 * 8 * 128 * 4),
+                        "_Q_WINDOWS": (8, 16)}),
+    # speculative proposals: 4-row segments at any row alignment, and a
+    # prefill chunk behind them
+    "spec": (8, 2, 32, 8, 6, 24, None,
+             [(0, 10, 4), (1, 3, 4), (2, 30, 4), (3, 0, 7)], {}),
+    # nothing live: every row is padding
+    "padding": (8, 2, 32, 8, 6, 8, None, [], {}),
+    "int8": (8, 2, 32, 8, 6, 16, jnp.int8,
+             [(0, 5, 6), (1, 21, 1), (2, 40, 3)], {}),
+    "fp8": (8, 2, 32, 8, 6, 16, jnp.float8_e4m3fn,
+            [(0, 5, 6), (1, 21, 1), (2, 40, 3)], {}),
+    # MHA at the serving model's 25 heads (G = 1), in head groups of 5
+    "mha25": (25, 25, 16, 8, 4, 12, None,
+              [(0, 3, 1), (1, 20, 1), (2, 6, 8)],
+              {"_HEAD_GROUP_VMEM": 5 * 4 * (4 * 16 * 128 + 16 * 384)}),
+    # GQA G = 4 with one KV head, decode and a chunk
+    "gqa4": (4, 1, 32, 8, 6, 16, None, [(0, 17, 1), (1, 2, 9)], {}),
+}
+
+
+@pytest.mark.parametrize("case", list(_PACKED_CASES))
+def test_paged_packed_attention_sweep(case, monkeypatch):
+    """Packed ragged kernel vs its gather oracle over one flat token
+    buffer: each case's segments packed in order, then a padding tail
+    (tok_pos == -1, slot 0) that must emit exact zeros."""
+    H, Hkv, D, page, Tb, budget, store, segs, patch = _PACKED_CASES[case]
+    for name, value in patch.items():
+        monkeypatch.setattr(PA, name, value)
+    S = max([s for s, _, _ in segs], default=0) + 1
+    P = Tb * S + 2
+    ks = jax.random.split(jax.random.PRNGKey(17), 5)
     q = jax.random.normal(ks[0], (budget, H, D))
     k_pages = jax.random.normal(ks[1], (P, page, Hkv, D))
     v_pages = jax.random.normal(ks[2], (P, page, Hkv, D))
-    bt = jnp.asarray(np.arange(1, 1 + S * T).reshape(S, T), jnp.int32)
-    # slot 0: 6-token prefill segment crossing the first page boundary;
-    # slot 1: decode segment (1 token) mid-page; slot 2 sits out; padding
-    # tail belongs to slot 0 but carries tok_pos == -1
-    tok_slot = jnp.asarray([0] * 6 + [1] + [0] * (budget - 7), jnp.int32)
-    tok_pos = jnp.asarray(list(range(page - 3, page + 3)) + [2 * page + 5]
-                          + [-1] * (budget - 7), jnp.int32)
+    scales = {}
+    if store is not None:
+        k_pages, v_pages = k_pages.astype(store), v_pages.astype(store)
+        scales = {f"{x}_scale": jax.random.uniform(
+            k, (P, 1, page), minval=0.005, maxval=0.02)
+            for x, k in zip("kv", ks[3:])}
+    bt = jnp.asarray(np.arange(1, 1 + S * Tb).reshape(S, Tb), jnp.int32)
+    tok_slot = [s for s, _, n in segs for _ in range(n)]
+    tok_pos = [p0 + i for _, p0, n in segs for i in range(n)]
+    live = len(tok_pos)
+    tok_slot = jnp.asarray(tok_slot + [0] * (budget - live), jnp.int32)
+    tok_pos = jnp.asarray(tok_pos + [-1] * (budget - live), jnp.int32)
     out = PA.paged_packed_attention(q, k_pages, v_pages, bt, tok_slot,
-                                    tok_pos, interpret=True)
+                                    tok_pos, interpret=True, **scales)
     ref = R.paged_packed_attention_ref(q, k_pages, v_pages, bt, tok_slot,
-                                       tok_pos)
+                                       tok_pos, **scales)
     assert out.shape == (budget, H, D)
     assert jnp.max(jnp.abs(out - ref)) < 2e-5
-    assert bool(jnp.all(out[7:] == 0))           # padding rows emit zeros
-    assert bool(jnp.all(ref[7:] == 0))
+    assert bool(jnp.all(out[live:] == 0))        # padding rows emit zeros
+    assert bool(jnp.all(ref[live:] == 0))
 
 
 def test_paged_packed_attention_t_eq_slots_matches_decode_kernel():

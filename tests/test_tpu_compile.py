@@ -79,6 +79,53 @@ def test_paged_packed_attention_compiles(one_chip, kv):
     assert "tpu_custom_call" in txt
 
 
+#: the serving cell's widths (``gpt2-1.5b-fal.serve.chat``): GPT-2 XL's 25
+#: heads of 64, 128-token pages, 152 pages, 36 slots of 8 blocks, and a
+#: 291-token budget
+SERVE_H, SERVE_D, SERVE_PAGE, SERVE_PAGES = 25, 64, 128, 152
+SERVE_SLOTS, SERVE_BLOCKS, SERVE_BUDGET = 36, 8, 291
+_DEF = re.compile(r"%(\S+) = (\w+)\[([\d,]*)\]\S* (\S+?)\((.*)")
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8", "fp8"])
+def test_paged_packed_attention_reads_the_pool_in_place(one_chip, kv):
+    """At the serving cell's widths the packed kernel's custom call takes
+    each page pool itself, or a bitcast of it: no transpose or copy of a
+    whole (152, ..., 25, 64) pool runs before it."""
+    store = KV_STORE[kv]
+    pool = ((SERVE_PAGES, SERVE_PAGE, SERVE_H, SERVE_D), store)
+    scale = ((SERVE_PAGES, 1, SERVE_PAGE), jnp.float32)
+
+    def f(q, kp, vp, bt, ts, tp, *scales):
+        ks, vs = scales if scales else (None, None)
+        return ops.paged_packed_attention(q, kp, vp, bt, ts, tp, k_scale=ks,
+                                          v_scale=vs, use_pallas=True)
+    txt = _compile_text(
+        f, one_chip, ((SERVE_BUDGET, SERVE_H, SERVE_D), jnp.bfloat16),
+        pool, pool, ((SERVE_SLOTS, SERVE_BLOCKS), jnp.int32),
+        ((SERVE_BUDGET,), jnp.int32), ((SERVE_BUDGET,), jnp.int32),
+        *([scale, scale] if kv != "bf16" else []))
+    defs = {}
+    for line in txt.splitlines():
+        m = _DEF.search(line)
+        if m:
+            defs[m.group(1)] = (m.group(3), m.group(4), m.group(5))
+    pools = {name: (op, rest) for name, (dims, op, rest) in defs.items()
+             if dims.startswith(f"{SERVE_PAGES},")
+             and {str(SERVE_H), str(SERVE_D)} <= set(dims.split(","))}
+    assert {op for op, _ in pools.values()} <= {"parameter", "bitcast"}, \
+        {n: op for n, (op, _) in pools.items()}
+    call = [rest for _, op, rest in defs.values() if op == "custom-call"
+            and 'custom_call_target="tpu_custom_call"' in rest]
+    assert len(call) == 1
+    fed = [p for p, (op, _) in pools.items() if op == "bitcast"
+           and re.search(rf"%{re.escape(p)}\b", call[0])]
+    assert len(fed) == 2, (fed, call[0][:300])
+    for p in fed:
+        src = re.match(r"%(\S+?)\)", pools[p][1]).group(1)
+        assert pools[src][0] == "parameter"
+
+
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_paged_decode_attention_compiles(one_chip, kv):
     def f(q, kp, vp, bt, sl, *scales):
