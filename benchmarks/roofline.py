@@ -68,8 +68,9 @@ def param_count(cfg):
         + cfg.n_heads * Dh * d
     if cfg.use_mla:
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        q_in = cfg.q_lora_rank or d      # no q LoRA: one direct projection
         attn = (d * cfg.q_lora_rank
-                + cfg.q_lora_rank * cfg.n_heads * (dn + dr)
+                + q_in * cfg.n_heads * (dn + dr)
                 + d * (cfg.kv_lora_rank + dr)
                 + cfg.kv_lora_rank * cfg.n_heads * (dn + dv)
                 + cfg.n_heads * dv * d)

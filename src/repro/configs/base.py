@@ -70,10 +70,20 @@ class ModelConfig:
     route_groups: int = 0
     route_group_limit: int = 4
     dense_d_ff: int = 0                 # d_ff of the dense layers (deepseek 18432)
+    # router scoring: 'softmax' (renormalised top-k of the softmax), or
+    # 'sigmoid' (DeepSeek-V3 noaux_tc: top-k of sigmoid scores plus a
+    # per-expert selection bias ``e_bias``, weights the chosen scores
+    # renormalised); either way the weights are scaled by routed_scale
+    router_score: str = "softmax"
+    routed_scale: float = 1.0           # routed_scaling_factor (deepseek 2.5)
+    # the expert share one chip of an expert-parallel deployment holds:
+    # the first experts_held of n_experts (0 = all); every token is still
+    # routed over all n_experts, dropless (models.moe.moe_apply_held)
+    experts_held: int = 0
 
     # MLA (deepseek) --------------------------------------------------------------
     use_mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0                # 0: a direct q projection (no q LoRA)
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -117,11 +127,19 @@ class ModelConfig:
     def __post_init__(self):
         assert self.connection in VALID_CONNECTIONS, self.connection
         assert self.family in VALID_FAMILIES, self.family
+        assert self.router_score in ("softmax", "sigmoid"), self.router_score
+        assert 0 <= self.experts_held <= self.n_experts, \
+            (self.experts_held, self.n_experts)
 
     # -------------------------------------------------------------------------
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def holds_expert_share(self) -> bool:
+        """True when this chip holds only a share of the routed experts."""
+        return 0 < self.experts_held < self.n_experts
 
     @property
     def is_attention_free(self) -> bool:
@@ -172,9 +190,11 @@ class ModelConfig:
                       route_group_limit=1,
                       n_shared_experts=min(self.n_shared_experts, 1),
                       first_dense_layers=min(self.first_dense_layers, 1),
-                      dense_d_ff=128 if self.dense_d_ff else 0)
+                      dense_d_ff=128 if self.dense_d_ff else 0,
+                      experts_held=2 if self.holds_expert_share else 0)
         if self.use_mla:
-            kw.update(q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=32,
+            kw.update(q_lora_rank=48 if self.q_lora_rank else 0,
+                      kv_lora_rank=32, qk_nope_head_dim=32,
                       qk_rope_head_dim=16, v_head_dim=32)
         if self.ssm_state:
             kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
@@ -203,6 +223,7 @@ ARCH_IDS = (
     "whisper-small",
     "gemma2-27b",
     "llama3.2-3b",
+    "moonlight-16b-a3b",
     # paper's own model family (reproduction baselines)
     "gpt2-117m",
     "gpt2-774m",

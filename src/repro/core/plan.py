@@ -278,6 +278,12 @@ class ExecutionPlan:
                     f"{EXPLICIT_TP_FAMILIES}) — running it would silently "
                     f"fall back to GSPMD and mislabel any numbers")
             self._check_divisibility(cfg)
+        if self.mesh is not None and cfg.holds_expert_share:
+            raise ValueError(
+                f"experts_held={cfg.experts_held} of n_experts="
+                f"{cfg.n_experts}: a held expert share runs on a single "
+                f"device; the expert-parallel paths on a mesh shard and "
+                f"exchange every expert of the layer")
         if self.mesh is not None:
             names = tuple(self.mesh.axis_names)
             if self.model_axis not in names:

@@ -84,6 +84,7 @@ _BASE = {
     # MLA: down-projections replicated (small), up-projections column
     "w_dq": (2, P()), "w_dkv": (2, P()), "w_kr": (2, P()),
     "w_uq": (2, P(None, MODEL)), "w_uk": (2, P(None, MODEL)),
+    "w_q": (2, P(None, MODEL)),      # direct q projection (no q LoRA)
     "w_uv": (2, P(None, MODEL)),
     # dense mlp
     "wi": (2, P(None, MODEL)), "wg": (2, P(None, MODEL)),

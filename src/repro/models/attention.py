@@ -563,10 +563,16 @@ def mla_init(key, cfg):
     rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     ks = jax.random.split(key, 8)
+    if rq:
+        q = {"w_dq": L.dense_init(ks[0], d, rq, cfg.param_dtype)["w"],
+             "q_norm": L.norm_init(rq, "rmsnorm", cfg.param_dtype),
+             "w_uq": L.dense_init(ks[1], rq, H * (dn + dr),
+                                  cfg.param_dtype)["w"]}
+    else:   # no q LoRA: one direct projection
+        q = {"w_q": L.dense_init(ks[0], d, H * (dn + dr),
+                                 cfg.param_dtype)["w"]}
     p = {
-        "w_dq": L.dense_init(ks[0], d, rq, cfg.param_dtype)["w"],
-        "q_norm": L.norm_init(rq, "rmsnorm", cfg.param_dtype),
-        "w_uq": L.dense_init(ks[1], rq, H * (dn + dr), cfg.param_dtype)["w"],
+        **q,
         "w_dkv": L.dense_init(ks[2], d, rkv, cfg.param_dtype)["w"],
         "kv_norm": L.norm_init(rkv, "rmsnorm", cfg.param_dtype),
         "w_kr": L.dense_init(ks[3], d, dr, cfg.param_dtype)["w"],
@@ -581,11 +587,15 @@ def mla_init(key, cfg):
 def _mla_q(p, cfg, x, positions):
     B, S = x.shape[:2]
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    # head count from the weight shape: w_uq may be a column (head) shard
-    # inside the explicit-TP shard_map (same contract as gqa_qkv)
-    H = p["w_uq"].shape[-1] // (dn + dr)
-    cq = L.norm_apply(p["q_norm"], x @ p["w_dq"].astype(x.dtype))
-    q = (cq @ p["w_uq"].astype(x.dtype)).reshape(B, S, H, dn + dr)
+    # head count from the weight shape: w_uq / w_q may be a column (head)
+    # shard inside the explicit-TP shard_map (same contract as gqa_qkv)
+    if "w_q" in p:
+        q = x @ p["w_q"].astype(x.dtype)
+    else:
+        cq = L.norm_apply(p["q_norm"], x @ p["w_dq"].astype(x.dtype))
+        q = cq @ p["w_uq"].astype(x.dtype)
+    H = q.shape[-1] // (dn + dr)
+    q = q.reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
     return q_nope, q_rope

@@ -113,21 +113,32 @@ def _mlp_input(cfg, p, x, a, a1_sig, is_block0):
         return fal.mlp_input(cfg, p, x, a, a1_sig)
 
 
+def _no_aux():
+    """``aux`` of a layer that routes nothing: (balance loss 0, no pairs)."""
+    return jnp.zeros((), jnp.float32), jnp.zeros((0,), jnp.int32)
+
+
 def _ffn_apply(p, cfg, h, kind, plan: ExecutionPlan):
-    """Returns (y, aux), under the ``mlp`` scope.  Under explicit TP ``y``
-    is a PARTIAL sum (dense: column-sharded wi/wg, row-sharded wo; MoE:
-    local experts only)."""
+    """Returns (y, aux), under the ``mlp`` scope, ``aux`` = (balance loss,
+    pairs routed to each held expert): the pairs of a held expert share
+    (single device only, ``plan.validate``), else empty.  Under explicit
+    TP ``y`` is a PARTIAL sum (dense: column-sharded wi/wg, row-sharded
+    wo; MoE: local experts only)."""
     with layer_scope("mlp"):
-        if kind == "moe":
-            axis = plan.tp_axis
-            if axis is not None:
-                return M.moe_apply_partial(p["ffn"], cfg, h, axis)
-            if plan.is_training_like and plan.is_sharded:
-                fn = (M.moe_apply_shard_slot if cfg.route_groups
-                      else M.moe_apply_sharded)
-                return fn(p["ffn"], cfg, h, plan)
-            return M.moe_apply(p["ffn"], cfg, h)
-        return L.mlp_apply(p["ffn"], h, cfg.mlp), jnp.zeros((), jnp.float32)
+        if kind != "moe":
+            return L.mlp_apply(p["ffn"], h, cfg.mlp), _no_aux()
+        axis = plan.tp_axis
+        if axis is not None:
+            y, aux = M.moe_apply_partial(p["ffn"], cfg, h, axis)
+        elif plan.is_training_like and plan.is_sharded:
+            fn = (M.moe_apply_shard_slot if cfg.route_groups
+                  else M.moe_apply_sharded)
+            y, aux = fn(p["ffn"], cfg, h, plan)
+        elif cfg.holds_expert_share:
+            return M.moe_apply_held(p["ffn"], cfg, h)
+        else:
+            y, aux = M.moe_apply(p["ffn"], cfg, h)
+        return y, (aux, jnp.zeros((0,), jnp.int32))
 
 
 def block_apply(p, cfg, x, a1_sig, positions, window, *, kind="dense",
@@ -142,7 +153,8 @@ def block_apply(p, cfg, x, a1_sig, positions, window, *, kind="dense",
     (selected when ``tok_slot`` is not None; the serving engine's tick).
 
     Returns (x_out, a_raw, aux, new_cache).  ``a_raw`` is this block's MHA
-    output (block 0 exports it as the first-attention signal).
+    output (block 0 exports it as the first-attention signal); ``aux`` is
+    ``_ffn_apply``'s (balance loss, pairs per held expert).
 
     Inside the explicit-TP shard_map (``plan.tp_axis`` set) the attention
     and FFN kernels see head-/hidden-/expert-sharded weights and return
@@ -272,7 +284,7 @@ def _block_apply_dual(p, cfg, x, a1_sig, window, *, kind,
                                                mlp_in, cache, block_tables,
                                                pos, n_valid,
                                                kernels=plan.kernels)
-        aux = jnp.zeros((), jnp.float32)
+        aux = _no_aux()
     else:
         # the sequential path's attention ops (the packed kernel serves the
         # MHA branch of a packed tick; arithmetic matches op-for-op, so

@@ -190,7 +190,9 @@ def decoder_stack_tp(p, cfg, x, positions, plan: ExecutionPlan):
     s_ax = max_ if sp else None
 
     def local(bp, x, positions):
-        x, aux = _run_decoder_blocks(bp, cfg, x, positions, inner)
+        # held expert shares, the only ones to count pairs, are
+        # single-device only (plan.validate)
+        x, aux, _ = _run_decoder_blocks(bp, cfg, x, positions, inner)
         if dax:
             # MoE aux differs per data shard (local routing); make it the
             # global mean so the out_spec can declare it replicated
@@ -208,7 +210,9 @@ def _run_decoder_blocks(p, cfg, x, positions, plan: ExecutionPlan):
     """Block 0 + the scanned dense/moe segments.  ONE implementation shared
     by the replicated/GSPMD path and the explicit-TP shard_map local body —
     the collective structure differs only through the plan the blocks see.
-    Returns (x, aux).
+    Returns (x, aux, pairs): ``pairs`` (MoE layers, held experts) counts the
+    pairs routed to each held expert of a held expert share; without one it
+    has no columns.
 
     Block 0 sits outside the layer scan; without its own remat its
     attention residuals (probs etc.) are stashed for backward
@@ -219,7 +223,8 @@ def _run_decoder_blocks(p, cfg, x, positions, plan: ExecutionPlan):
                                      kind=_layer_kind(cfg, 0), is_block0=True,
                                      plan=plan),
         cfg)
-    x, a1_raw, aux, _ = block0(p["block0"], x)
+    x, a1_raw, (aux, pairs0), _ = block0(p["block0"], x)
+    pairs = [pairs0[None]]
     a1_sig = fal.first_attention_signal(cfg, p["block0"], a1_raw)
 
     i = 1
@@ -227,27 +232,32 @@ def _run_decoder_blocks(p, cfg, x, positions, plan: ExecutionPlan):
         if p.get(name) is not None:
             n = jax.tree.leaves(p[name])[0].shape[0]
             ws = jnp.asarray(wsched[i:i + n], jnp.int32)
-            x, aux_s = _run_stack(p[name], cfg, x, a1_sig, positions, ws,
-                                  kind, plan)
+            x, aux_s, pairs_s = _run_stack(p[name], cfg, x, a1_sig,
+                                           positions, ws, kind, plan)
             aux += aux_s
+            pairs.append(pairs_s)
             i += n
-    return x, aux
+    # layers that route nothing to held experts count no pairs (no columns)
+    held = [q for q in pairs if q.shape[-1]]
+    return x, aux, (jnp.concatenate(held) if held
+                    else jnp.zeros((0, 0), jnp.int32))
 
 
 def _run_stack(p_stack, cfg, x, a1_sig, positions, windows, kind,
                plan: ExecutionPlan):
-    """Scan blocks over stacked params.  Returns (x, aux_sum)."""
+    """Scan blocks over stacked params.  Returns (x, aux_sum, pairs):
+    ``pairs`` stacks each layer's pairs per held expert."""
     def body(carry, xs):
         h, aux = carry
         pb, w = xs
-        h, _, aux_i, _ = BL.block_apply(
+        h, _, (aux_i, pairs), _ = BL.block_apply(
             pb, cfg, h, a1_sig, positions, w, kind=kind, plan=plan)
-        return (h, aux + aux_i), None
+        return (h, aux + aux_i), pairs
 
     body = _maybe_remat(body, cfg)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                               (p_stack, windows))
-    return x, aux
+    (x, aux), pairs = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                   (p_stack, windows))
+    return x, aux, pairs
 
 
 def _decoder_forward(p, cfg, batch, plan: ExecutionPlan, want="logits"):
@@ -258,15 +268,19 @@ def _decoder_forward(p, cfg, batch, plan: ExecutionPlan, want="logits"):
                       batch.get("image_embeds"))
     x = constrain_batch(x, plan)
 
+    extras = {}
     if plan.use_explicit_tp:
         x, aux = decoder_stack_tp(p, cfg, x, positions, plan)
     else:
-        x, aux = _run_decoder_blocks(p, cfg, x, positions, plan)
+        x, aux, pairs = _run_decoder_blocks(p, cfg, x, positions, plan)
+        if cfg.holds_expert_share:
+            extras["expert_pairs"] = pairs
 
     if want == "hidden":
-        return None, aux, {"hidden": x}
+        return None, aux, dict(extras, hidden=x)
     logits = _logits(p, cfg, x)
-    extras = {"hidden": x} if cfg.mtp_depth else {}
+    if cfg.mtp_depth:
+        extras["hidden"] = x
     return logits, aux, extras
 
 
@@ -1136,6 +1150,13 @@ def loss_fn(params, cfg, batch, plan=None):
     ce = tail(params, hidden=extras["hidden"], tokens=tokens)
     loss = ce + cfg.router_aux_coef * aux
     metrics = {"ce": ce, "aux": aux}
+    if "expert_pairs" in extras:
+        # per MoE layer: pairs routed to each held expert, and the held
+        # share of all the layer's (token, expert) pairs
+        pairs = extras["expert_pairs"]
+        metrics["expert_pairs"] = pairs
+        metrics["held_share"] = jnp.sum(pairs, -1) / (tokens.size
+                                                      * cfg.top_k)
     if cfg.mtp_depth and "mtp" in params:
         mtp_fn = jax.checkpoint(functools.partial(_mtp_loss, cfg=cfg)) \
             if cfg.remat else functools.partial(_mtp_loss, cfg=cfg)

@@ -1,19 +1,30 @@
 """Mixture-of-Experts FFN.
 
-Two execution paths:
+Execution paths:
 
 * ``moe_apply`` (pure jnp): capacity-based top-k dispatch via one-hot cumsum +
   scatter/gather.  Used on a single device (smoke tests), for decode (token
   counts are tiny), and as the *oracle* for the sharded path.
+* ``moe_apply_held`` (single device): the expert share one chip of an
+  expert-parallel deployment holds (``cfg.experts_held`` of
+  ``cfg.n_experts``).  Every token is routed over all experts with the full
+  router; the held experts' part is computed for every pair routed to them,
+  dropless, by grouped matmuls over the live rows only.
+* ``moe_apply_partial`` (inside the explicit-TP shard_map): local experts'
+  partial sum, completed by the block's all-reduce.
 * ``moe_apply_sharded`` (shard_map): expert parallelism over the ``model``
   mesh axis with explicit ``jax.lax.all_to_all`` dispatch/return — the
   production train path.  Collective bytes are visible in the lowered HLO and
   feed the roofline's ICI term.
 
-Routing: softmax top-k with renormalisation, capacity factor ``cf`` (tokens
-above capacity are dropped — standard fixed-shape TPU practice; recorded as a
-deviation from DeepSeek's dropless routing in DESIGN.md §7).  Aux
-load-balance loss per Switch/DeepSeek: ``E * sum_e f_e * P_e``.
+Routing (``_route``): softmax top-k renormalised, or DeepSeek-V3's sigmoid
+scores chosen with a per-expert selection bias (``cfg.router_score``); the
+gates are scaled by ``cfg.routed_scale``.  Where routing is dropless:
+``moe_apply_held`` always; the capacity paths drop tokens past capacity
+``cf`` (standard fixed-shape TPU practice) and are dropless only when the
+capacity covers every pair (``ModelConfig.reduced`` sets ``cf = E``);
+PERF.md records the cells that run each.  Aux load-balance loss per
+Switch/DeepSeek: ``E * sum_e f_e * P_e``.
 """
 from __future__ import annotations
 
@@ -26,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import layers as L
+from repro.obs.trace import sub_scope
 
 
 # ------------------------------------------------------------------------- #
@@ -33,15 +45,20 @@ from repro.models import layers as L
 # ------------------------------------------------------------------------- #
 def moe_init(key, cfg):
     d, E, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    n = cfg.experts_held if cfg.holds_expert_share else E   # experts here
     ks = jax.random.split(key, 5)
     s_in, s_out = 1.0 / np.sqrt(d), 1.0 / np.sqrt(f)
     pd = jnp.dtype(cfg.param_dtype)
     p = {
         "router": jax.random.normal(ks[0], (d, E), pd) * s_in,
-        "wi": jax.random.normal(ks[1], (E, d, f), pd) * s_in,
-        "wg": jax.random.normal(ks[2], (E, d, f), pd) * s_in,
-        "wo": jax.random.normal(ks[3], (E, f, d), pd) * s_out,
+        "wi": jax.random.normal(ks[1], (n, d, f), pd) * s_in,
+        "wg": jax.random.normal(ks[2], (n, d, f), pd) * s_in,
+        "wo": jax.random.normal(ks[3], (n, f, d), pd) * s_out,
     }
+    if cfg.router_score == "sigmoid":
+        # selection bias of noaux_tc routing: it takes part in the choice
+        # only and gets no gradient (models.moe._route)
+        p["e_bias"] = jnp.zeros((E,), pd)
     if cfg.n_shared_experts:
         p["shared"] = L.mlp_init(ks[4], d, f * cfg.n_shared_experts,
                                  "swiglu", cfg.param_dtype)
@@ -55,29 +72,60 @@ def _capacity(T, k, E, cf):
 # ------------------------------------------------------------------------- #
 # routing + dispatch plumbing (shared by both paths)
 # ------------------------------------------------------------------------- #
+def _group_mask(sel, cfg):
+    """(T, E) affinities -> (T, G) 0/1: the ``route_group_limit`` groups of
+    each token with the largest sums of their top-2 affinities
+    (DeepSeek-V3 node-limited routing)."""
+    G = cfg.route_groups
+    sg = sel.reshape(-1, G, sel.shape[-1] // G)
+    top2 = jax.lax.top_k(sg, min(2, sg.shape[-1]))[0].sum(-1)      # (T, G)
+    _, gidx = jax.lax.top_k(top2, cfg.route_group_limit)          # (T, L)
+    return jnp.zeros_like(top2).at[
+        jnp.arange(top2.shape[0])[:, None], gidx].set(1.0)
+
+
 def _route(p, cfg, x2d):
-    """x2d: (T, d) -> (weights (T,k), experts (T,k), aux_loss)."""
+    """x2d: (T, d) -> (weights (T,k), experts (T,k), aux_loss).
+
+    softmax: top-k of the softmax, renormalised.  sigmoid (DeepSeek-V3
+    noaux_tc): top-k of the sigmoid scores plus ``e_bias``, which takes part
+    in the choice only; the weights are the chosen scores, renormalised.
+    Both scaled by ``cfg.routed_scale``.  With ``cfg.route_groups`` the
+    choice is limited to each token's best groups."""
     logits = (x2d @ p["router"].astype(x2d.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                 # (T, E)
     E = cfg.n_experts
-    if cfg.route_groups:
-        # group-limited routing (DeepSeek-V3 node-limited top-k): keep only
-        # the top `route_group_limit` groups per token (group score = sum of
-        # top-2 affinities within the group), mask the rest.
-        G = cfg.route_groups
-        pg = probs.reshape(-1, G, E // G)
-        top2 = jax.lax.top_k(pg, min(2, E // G))[0].sum(-1)       # (T, G)
-        _, gidx = jax.lax.top_k(top2, cfg.route_group_limit)      # (T, L)
-        gmask = jnp.zeros_like(top2).at[
-            jnp.arange(top2.shape[0])[:, None], gidx].set(1.0)
-        probs = (pg * gmask[:, :, None]).reshape(-1, E)
-    w, e = jax.lax.top_k(probs, cfg.top_k)                  # (T, k)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)                      # (T, E)
+        sel = scores + jax.lax.stop_gradient(
+            p["e_bias"].astype(jnp.float32))
+        if cfg.route_groups:
+            keep = jnp.repeat(_group_mask(sel, cfg), E // cfg.route_groups,
+                              axis=1)
+            sel = jnp.where(keep > 0, sel, -jnp.inf)
+        _, e = jax.lax.top_k(sel, cfg.top_k)                 # (T, k)
+        w = jnp.take_along_axis(scores, e, axis=-1)
+        # the balance loss reads each token's scores as a distribution
+        probs = scores / jnp.sum(scores, -1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)              # (T, E)
+        if cfg.route_groups:
+            G = cfg.route_groups
+            probs = (probs.reshape(-1, G, E // G)
+                     * _group_mask(probs, cfg)[:, :, None]).reshape(-1, E)
+        w, e = jax.lax.top_k(probs, cfg.top_k)               # (T, k)
     w = w / jnp.maximum(jnp.sum(w, -1, keepdims=True), 1e-9)
+    if cfg.routed_scale != 1.0:
+        w = w * cfg.routed_scale
     # load-balance aux: fraction routed vs mean prob
     f_e = jnp.mean(jax.nn.one_hot(e, E, dtype=jnp.float32).sum(1), 0)  # (E,)
     P_e = jnp.mean(probs, 0)
     aux = E * jnp.sum(f_e * P_e)
     return w.astype(x2d.dtype), e, aux
+
+
+def _route_params(p):
+    """The router's parameters, replicated on every expert shard."""
+    return {k: p[k] for k in ("router", "e_bias") if k in p}
 
 
 def _dispatch_indices(e, k, E, C):
@@ -121,6 +169,58 @@ def moe_apply(p, cfg, x):
 
 
 # ------------------------------------------------------------------------- #
+# held expert share, dropless (single device)
+# ------------------------------------------------------------------------- #
+def moe_apply_held(p, cfg, x, offset=0):
+    """The held experts' part of the layer, dropless.  x: (B, S, d) ->
+    (y, (aux, pairs)).
+
+    ``p`` holds ``n`` experts (wi/wg/wo ``(n, ...)``), experts
+    ``offset .. offset + n`` of ``cfg.n_experts``, and the full router.
+    Every token is routed over all experts; each (token, expert) pair
+    whose expert is held is computed, and the rest are left out: they
+    belong to experts other chips hold.  A token has at most
+    ``min(top_k, n)`` pairs here, so one static buffer of
+    ``T * min(top_k, n)`` rows, sorted by expert, takes every pair; the
+    grouped matmuls compute its live rows only.  Each row's hidden units
+    are scaled by its gate before the down projection, and its output is
+    added into its token's row in float32, with no ``(T, top_k, d)``
+    buffer.  The shared experts are added for every
+    token (each chip computes them for its own tokens, so the shares of
+    one layer count them once).  ``pairs`` (n,) counts the pairs routed
+    to each held expert."""
+    B, S, d = x.shape
+    T, k, n = B * S, cfg.top_k, p["wi"].shape[0]
+    N = T * min(k, n)
+    x2d = x.reshape(T, d)
+    with sub_scope("router"):
+        w, e, aux = _route(p, cfg, x2d)
+    with sub_scope("experts"):
+        local = e - offset                                       # (T, k)
+        key = jnp.where((local >= 0) & (local < n), local, n).reshape(-1)
+        order = jnp.argsort(key, stable=True)[:N]   # held pairs first
+        pairs = jnp.bincount(key, length=n + 1)[:n]
+        live = (jnp.arange(N) < jnp.sum(pairs))[:, None]
+        tok = order // k
+        # ragged_dot computes the first sum(pairs) rows only, and what the
+        # chip's kernel leaves past them is not defined: ``where`` on
+        # ``live`` keeps those rows, and their cotangents, out of every
+        # result and gradient
+        rows = jnp.where(live, x2d[tok], 0)                      # (N, d)
+        gate = jnp.where(live, w.reshape(-1)[order][:, None], 0)
+        dt = x.dtype
+        h = (jax.nn.silu(jax.lax.ragged_dot(rows, p["wg"].astype(dt), pairs))
+             * jax.lax.ragged_dot(rows, p["wi"].astype(dt), pairs))
+        out = jax.lax.ragged_dot(jnp.where(live, h * gate, 0),
+                                 p["wo"].astype(dt), pairs)
+        y = jnp.zeros((T, d), jnp.float32).at[tok].add(
+            jnp.where(live, out, 0).astype(jnp.float32)).astype(dt)
+    if "shared" in p:
+        y = y + L.mlp_apply(p["shared"], x2d, "swiglu")
+    return y.reshape(B, S, d), (aux, pairs)
+
+
+# ------------------------------------------------------------------------- #
 # explicit-TP partial-sum path (inside model.decoder_stack_tp's shard_map)
 # ------------------------------------------------------------------------- #
 def moe_apply_partial(p, cfg, x, axis="model"):
@@ -143,7 +243,7 @@ def moe_apply_partial(p, cfg, x, axis="model"):
     T, k = B * S, cfg.top_k
     C = _capacity(T, k, E, cfg.capacity_factor)
     x2d = x.reshape(T, d)
-    w, e, aux = _route({"router": p["router"]}, cfg, x2d)
+    w, e, aux = _route(p, cfg, x2d)
     ef, pos, valid = _dispatch_indices(e, k, E, C)
     tok = jnp.repeat(jnp.arange(T), k)
     lo = jax.lax.axis_index(axis) * E_loc if E_loc != E else 0
@@ -177,7 +277,7 @@ def moe_apply_sharded(p, cfg, x, plan):
     E = cfg.n_experts
     assert E % M == 0, (E, M)
 
-    def local_fn(router, wi, wg, wo, shared, x_loc):
+    def local_fn(route, wi, wg, wo, shared, x_loc):
         # x_loc: (b, S/M, d) — tokens are sharded over the model axis too
         # (replicating them would duplicate routing + expert compute x M,
         # EXPERIMENTS.md §Perf D4)
@@ -185,8 +285,7 @@ def moe_apply_sharded(p, cfg, x, plan):
         T, k = b * S, cfg.top_k
         C = _capacity(T, k, E, cfg.capacity_factor)
         x2d = x_loc.reshape(T, d)
-        pl = {"router": router}
-        w, e, aux = _route(pl, cfg, x2d)
+        w, e, aux = _route(route, cfg, x2d)
         ef, pos, valid = _dispatch_indices(e, k, E, C)
         tok = jnp.repeat(jnp.arange(T), k)
         buf = jnp.zeros((E, C, d), x_loc.dtype)
@@ -211,13 +310,15 @@ def moe_apply_sharded(p, cfg, x, plan):
         return y.reshape(b, S, d), aux
 
     shared = p.get("shared")
-    in_specs = (P(), P(model_axis), P(model_axis), P(model_axis),
+    route = _route_params(p)
+    in_specs = (jax.tree.map(lambda _: P(), route),
+                P(model_axis), P(model_axis), P(model_axis),
                 None if shared is None else jax.tree.map(lambda _: P(), shared),
                 P(data_axes, model_axis, None))
     out_specs = (P(data_axes, model_axis, None), P())
     fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return fn(p["router"], p["wi"], p["wg"], p["wo"], shared, x)
+    return fn(route, p["wi"], p["wg"], p["wo"], shared, x)
 
 
 # ------------------------------------------------------------------------- #
@@ -243,14 +344,14 @@ def moe_apply_shard_slot(p, cfg, x, plan):
     L = cfg.route_group_limit if cfg.route_groups else min(cfg.top_k, M)
     assert E % M == 0
 
-    def local_fn(router, wi, wg, wo, shared, x_loc):
+    def local_fn(route, wi, wg, wo, shared, x_loc):
         # x_loc: (b, S/M, d) — sequence sharded over model (§Perf D4)
         b, S, d = x_loc.shape
         T, k = b * S, cfg.top_k
         E_loc = E // M
         Cs = _capacity(T, L, M, cfg.capacity_factor)   # slots per dest shard
         x2d = x_loc.reshape(T, d)
-        w, e, aux = _route({"router": router}, cfg, x2d)
+        w, e, aux = _route(route, cfg, x2d)
 
         # destination shard per (token, k-slot); dedupe to per-token shard
         # slots: shard s needed iff any expert maps to it
@@ -333,10 +434,12 @@ def moe_apply_shard_slot(p, cfg, x, plan):
         return LL.mlp_apply(shared, x2d, "swiglu")
 
     shared = p.get("shared")
-    in_specs = (P(), P(model_axis), P(model_axis), P(model_axis),
+    route = _route_params(p)
+    in_specs = (jax.tree.map(lambda _: P(), route),
+                P(model_axis), P(model_axis), P(model_axis),
                 None if shared is None else jax.tree.map(lambda _: P(), shared),
                 P(data_axes, model_axis, None))
     out_specs = (P(data_axes, model_axis, None), P())
     fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    return fn(p["router"], p["wi"], p["wg"], p["wo"], shared, x)
+    return fn(route, p["wi"], p["wg"], p["wo"], shared, x)

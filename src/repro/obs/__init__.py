@@ -114,6 +114,19 @@ sample      serve/scheduler.py  make_packed_step's fused sampler
 optimizer   train/step.py  adamw_update (clip, global norm, moments)
 ==========  =================================================================
 
+``obs.trace.SUB_SCOPES`` name parts of one layer (``obs.trace.sub_scope``),
+nested inside its scope, so a reader of the table above still counts their
+ops under that layer:
+
+==========  =====  ========================================================
+sub-scope   layer  opened around
+==========  =====  ========================================================
+router      mlp    models/moe.py  moe_apply_held: _route (router matmul,
+                   scores, top-k, gates, balance loss)
+experts     mlp    models/moe.py  moe_apply_held: the held experts' sort,
+                   gathers, grouped matmuls and combine
+==========  =====  ========================================================
+
 The engine's host spans (``PagedEngine``, ``annotate=True``, so they sit on
 the profiler's clock beside the device ops): ``engine.tick`` around
 ``engine.admit`` (``_admit``), ``engine.plan`` (``_plan_pack`` and

@@ -23,7 +23,8 @@ be laid over each other.
 
 ``LAYER_SCOPES`` is the fixed table of layer names the programs open with
 ``layer_scope`` (a ``jax.named_scope``): they exist only in the traced
-program's metadata and cost nothing at run time.
+program's metadata and cost nothing at run time.  ``SUB_SCOPES`` name parts
+inside one layer (``sub_scope``) in the same way.
 """
 from __future__ import annotations
 
@@ -71,6 +72,20 @@ def layer_scope(name: str):
     """``jax.named_scope`` for one name of ``LAYER_SCOPES``."""
     if name not in LAYER_SCOPES:
         raise ValueError(f"{name!r} is not a layer scope {LAYER_SCOPES}")
+    import jax
+    return jax.named_scope(name)
+
+
+#: scopes opened inside one layer scope, each with the layer it lies in;
+#: a reader of ``LAYER_SCOPES`` alone counts their ops under that layer
+SUB_SCOPES = {"router": "mlp", "experts": "mlp"}
+
+
+def sub_scope(name: str):
+    """``jax.named_scope`` for one name of ``SUB_SCOPES``, opened inside
+    its layer's scope."""
+    if name not in SUB_SCOPES:
+        raise ValueError(f"{name!r} is not a sub-scope {tuple(SUB_SCOPES)}")
     import jax
     return jax.named_scope(name)
 

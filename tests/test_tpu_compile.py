@@ -293,3 +293,35 @@ def test_packed_tick_ops_carry_one_layer_scope(one_chip):
     every = {innermost_scope(s) for line in txt.splitlines()
              for s in _STACK.findall(line)}
     assert {"kv_write", "sample", "head", "embed"} <= every
+
+
+def test_held_expert_share_compiles_to_grouped_kernels(one_chip):
+    """Moonlight's held share at its real widths (8 of 64 experts of
+    1408, top 6, one row of 8192 tokens of 2048), forward and backward:
+    the grouped matmuls over the held pairs compile to the chip's grouped
+    kernel (a Mosaic custom call), not to dense products over the
+    49,152-row buffer, and the routing and combine around them fit."""
+    from repro.models import moe as MOE
+    cfg = get_config("moonlight-16b-a3b").replace(experts_held=8,
+                                                  dtype="bfloat16")
+    n, d, f, E = 8, cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    bf = jnp.bfloat16
+
+    def f_(x, router, e_bias, wi, wg, wo, sw, sg, so):
+        p = {"router": router, "e_bias": e_bias, "wi": wi, "wg": wg,
+             "wo": wo, "shared": {"wi": sw, "wg": sg, "wo": so}}
+        y, (aux, _) = MOE.moe_apply_held(p, cfg, x)
+        return jnp.sum(y.astype(jnp.float32)) + aux
+
+    fs = 2 * f
+    txt = _compile_text(jax.grad(f_, argnums=(0, 1, 3, 4, 5)), one_chip,
+                        ((1, 8192, d), bf), ((d, E), bf), ((E,), bf),
+                        ((n, d, f), bf), ((n, d, f), bf), ((n, f, d), bf),
+                        ((d, fs), bf), ((d, fs), bf), ((fs, d), bf))
+    kernels = re.findall(r'custom_call_target="tpu_custom_call"', txt)
+    # three products forward, each with a rows' and a weights' gradient
+    assert len(kernels) >= 9, len(kernels)
+    # no XLA matmul over the buffer: its products are the kernels'
+    dense = [ln for ln in txt.splitlines() if "49152" in ln
+             and re.search(r"= \S+ (dot|convolution)\(", ln)]
+    assert not dense, dense[:3]
